@@ -31,6 +31,20 @@ def timeit(fn, *args, warmup: int = 1, iters: int = 3) -> float:
     return times[len(times) // 2]
 
 
+def require_cpu_rehearsal(name: str) -> None:
+    """Refuse to run a CPU rehearsal on a TPU.
+
+    fig_scaling, fig_elastic and fig_recovery start child processes that
+    run on virtual CPU devices (``--xla_force_host_platform_device_count``,
+    which only the CPU backend honours). On a TPU host this process holds
+    the chip, so a child that reaches for it fails or hangs.
+    """
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{name} is a CPU rehearsal on virtual devices and does not run "
+            "on a TPU; run it with JAX_PLATFORMS=cpu")
+
+
 def emit(name: str, seconds: float | None, derived: str):
     us = f"{seconds * 1e6:.1f}" if seconds is not None else ""
     print(f"{name},{us},{derived}")

@@ -35,7 +35,7 @@ from repro.core.exact import chunked_mvm
 from repro.core.lattice import build_lattice_auto
 from repro.core.stencil import make_stencil
 from repro.kernels.blur import ref as blur_ref
-from repro.kernels.blur.ops import choose_backend
+from repro.kernels.blur.ops import resolve_backend
 
 SIZES = [1000, 4000, 16000, 64000]
 D = 8
@@ -61,8 +61,7 @@ def main():
         build_s = time.perf_counter() - t0
         m = int(lat.m)
 
-        fused_backend = choose_backend(n=n, d=D, r=st.r, cap1=lat.cap + 1,
-                                       c=1)
+        fused_backend = resolve_backend("auto")
         per_dir = jax.jit(lambda lt, vv: filtering.filter_mvm(
             lt, vv, w, backend="xla"))
         fused = jax.jit(lambda lt, vv: filtering.filter_mvm(

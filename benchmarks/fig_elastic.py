@@ -228,7 +228,8 @@ def run_elastic(root: str | pathlib.Path, *, epochs: int = EPOCHS,
 
 
 def main():
-    from benchmarks.common import emit, write_json
+    from benchmarks.common import emit, require_cpu_rehearsal, write_json
+    require_cpu_rehearsal("fig_elastic")
     with tempfile.TemporaryDirectory(prefix="elastic_ckpt_") as td:
         payload = run_elastic(td)
     s = payload["summary"]

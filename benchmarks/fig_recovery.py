@@ -266,7 +266,8 @@ def run_recovery(store_root: str | pathlib.Path, *,
 
 
 def main():
-    from benchmarks.common import emit, write_json
+    from benchmarks.common import emit, require_cpu_rehearsal, write_json
+    require_cpu_rehearsal("fig_recovery")
     with tempfile.TemporaryDirectory(prefix="recovery_store_") as td:
         payload = run_recovery(td)
     s = payload["summary"]

@@ -171,10 +171,7 @@ def measure_grad_collectives(n: int = 300, *, variance_rank: int = 6) -> dict:
     """Collective counts on the query-gradient jaxpr under the
     replicated-table mesh — asserted all-zero before the artifact is
     written (DESIGN.md §15 zero-collective gradient contract)."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     x, y = _pendulum_data(n)
@@ -190,7 +187,7 @@ def measure_grad_collectives(n: int = 300, *, variance_rank: int = 6) -> dict:
         return jax.grad(f)(q)
 
     fn = shard_map(grad_core, mesh=mesh, in_specs=(P(), P("data")),
-                   out_specs=P("data"), check_rep=False)
+                   out_specs=P("data"), check_vma=False)
     counts = collective_counts(fn, pred, jnp.zeros((64, 3), jnp.float32))
     assert all(v == 0 for v in counts.values()), (
         f"query-space gradient is not collective-free: {counts}")

@@ -110,7 +110,8 @@ def main() -> None:
     if os.environ.get(_WORKER_ENV) == "1":
         _worker()
         return
-    from benchmarks.common import emit, write_json
+    from benchmarks.common import emit, require_cpu_rehearsal, write_json
+    require_cpu_rehearsal("fig_scaling")
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +

@@ -17,6 +17,9 @@ from repro.core.stencil import make_stencil
 from repro.data.synthetic_uci import load
 from repro.gp import GPParams, SimplexGP, SimplexGPConfig
 from repro.gp.mll import mll_value_and_grad
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--n", type=int, default=100_000)

@@ -11,6 +11,9 @@ import numpy as np
 from repro.configs import ARCH_IDS, smoke
 from repro.launch.serve import Request, ServeEngine
 from repro.models import build
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--arch", default="llama3.2-3b", choices=ARCH_IDS)

@@ -13,6 +13,9 @@ import jax.numpy as jnp
 from repro.configs import get as get_config
 from repro.launch.train import TrainConfig, make_model_and_step, run
 import repro.launch.train as train_mod
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=300)

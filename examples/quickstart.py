@@ -8,6 +8,9 @@ import numpy as np
 
 from repro.gp import (GPParams, SimplexGP, SimplexGPConfig, fit, nll,
                       posterior, rmse)
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # --- data: a smooth function of 4 inputs + noise ---------------------------
 rng = np.random.default_rng(0)

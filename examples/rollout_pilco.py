@@ -43,6 +43,9 @@ import numpy as np
 
 from repro.gp import GPParams, SimplexGP, SimplexGPConfig, freeze_multi
 from repro.gp.serve import predict_multi
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # --- the true system: a damped pendulum, angle th / velocity om ------------
 DT = 0.1

@@ -24,6 +24,9 @@ from repro.gp import (GPParams, SimplexGP, SimplexGPConfig, fit, freeze,
 from repro.gp.serve import predict
 from repro.launch import EngineConfig, GPServeEngine, PredictorStore
 from repro.runtime.faults import corrupt_checkpoint
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # --- data: a smooth function of 4 inputs + noise ---------------------------
 rng = np.random.default_rng(0)

@@ -340,11 +340,7 @@ class LatticeCache:
         # and the key matches the stored Lattice.build_backend provenance
         from repro.kernels.hash import ops as hash_ops
         from repro.sharding.simplex import mesh_fingerprint
-        n, d = z.shape
-        cap_val = cap if cap is not None else lat_mod.default_capacity(n, d)
-        resolved = hash_ops.resolve_build_backend(
-            build_backend, hcap=hash_ops.hash_capacity(cap_val),
-            npk=max(1, (d + 1) // 2))
+        resolved = hash_ops.resolve_build_backend(build_backend)
         key = (tag, ls_key, float(spacing), int(r),
                None if cap is None else int(cap), self.layout_key(z),
                resolved, mesh_fingerprint(mesh))
@@ -369,8 +365,7 @@ def _embed_queries(zq: Array, spacing: float, dtype):
     path's one-embed-per-batch pin (``lattice.embed_count``) counts."""
     b, d = zq.shape
     keys, w, rank = lat_mod.simplex_embed_ranked(zq, spacing)
-    q_packed = jnp.stack(
-        lat_mod._pack_key_cols(keys.reshape(b * (d + 1), d + 1)), axis=1)
+    q_packed = jnp.stack(lat_mod._pack_key_cols(keys), axis=1)
     # queries whose coordinates overflow the 16-bit packing could alias
     # real keys — force all their vertices to miss (reported as mass 1)
     ok = jnp.all(jnp.abs(keys) <= lat_mod._PACK_LIMIT, axis=(1, 2))

@@ -319,17 +319,24 @@ _TAG_SHIFT = 30  # neighbor-sort tag bit position (payload ids < 2^30)
 
 
 def _pack_key_cols(keys: Array) -> list[Array]:
-    """(N, d+1) int32 coords -> ceil(d/2) int32 sort columns."""
-    n, c = keys.shape
-    use = keys[:, : c - 1]  # last coord = -(sum of others)
+    """(..., d+1) int32 coords -> ceil(d/2) int32 sort columns, one entry
+    per key in row-major order of the leading axes.
+
+    Packs along the last axis BEFORE flattening the leading ones: on the
+    TPU, reshaping the (..., d+1) coordinate array itself into key rows is
+    a relayout whose compile time grows with the row count (about 90 s for
+    protein's 203k training vertices), while the packed columns flatten
+    for free.
+    """
+    c = keys.shape[-1]  # the last coord is -(sum of others): not packed
     cols = []
     for start in range(0, c - 1, 2):
-        hi = use[:, start].astype(jnp.int32) + _PACK_BIAS
+        hi = keys[..., start].astype(jnp.int32) + _PACK_BIAS
         if start + 1 < c - 1:
-            lo = use[:, start + 1].astype(jnp.int32) + _PACK_BIAS
+            lo = keys[..., start + 1].astype(jnp.int32) + _PACK_BIAS
         else:
             lo = jnp.zeros_like(hi)
-        cols.append((hi << 16) | lo)
+        cols.append(((hi << 16) | lo).reshape(-1))
     return cols
 
 
@@ -399,8 +406,7 @@ def estimate_m(z: Array, spacing: float, *, sample: int = 4096) -> int:
 
     def distinct(zz) -> int:
         keys, _ = simplex_embed(zz, spacing)
-        packed = jnp.stack(_pack_key_cols(
-            keys.reshape(zz.shape[0] * (d + 1), d + 1)), axis=1)
+        packed = jnp.stack(_pack_key_cols(keys), axis=1)
         return int(_distinct_keys(
             packed, hash_ops.hash_capacity(zz.shape[0] * (d + 1))))
 
@@ -459,6 +465,8 @@ def suggest_capacity(n: int, d: int, spacing: float, *, r: int = 1,
     instead of spilling the fusion. A guess that does not fit even
     unrounded is returned as-is — occupancy beats fusion (the blocked/XLA
     tiers handle oversized tables; under-capacity would corrupt results).
+    The fused plan is that of the explicit ``fused_pallas`` tier; ``auto``
+    runs ``fused_xla``, which has no VMEM plan (ROADMAP S2).
     """
     if z is not None and not isinstance(z, jax.core.Tracer):
         guess = max(1024, int(1.25 * estimate_m(z, spacing, sample=sample)))
@@ -516,19 +524,18 @@ def build_lattice(z: Array, *, spacing: float, r: int = 1,
         Prefer an auto-sized cap (``build_lattice_auto`` outside jit) — every
         per-lattice-point array scales with it.
       backend: build path (kernels/hash/ops.py policy). "auto" resolves to
-        the hash build (hash_pallas on TPU when the table fits VMEM,
-        hash_xla elsewhere); "sort" keeps the original two-pass
-        lexicographic-sort build as the bit-exact lex-ordered oracle. All
-        paths produce operator-equivalent lattices (same splat->blur->slice
-        results up to slot permutation + f32 accumulation noise) with
-        identical overflow/pack_overflow semantics.
+        the hash build (hash_xla on every host); "sort" keeps the
+        original two-pass lexicographic-sort build as the bit-exact
+        lex-ordered oracle. All paths produce operator-equivalent
+        lattices (same splat->blur->slice results up to slot permutation
+        + f32 accumulation noise) with identical overflow/pack_overflow
+        semantics.
     """
     n, d = z.shape
     if cap is None:
         cap = default_capacity(n, d)
     _BUILD_STATS["builds"] += 1
-    resolved = hash_ops.resolve_build_backend(
-        backend, hcap=hash_ops.hash_capacity(cap), npk=max(1, (d + 1) // 2))
+    resolved = hash_ops.resolve_build_backend(backend)
     if resolved == "sort":
         return _build_lattice_impl(z, spacing=spacing, r=r, cap=cap)
     return _build_lattice_hash_impl(z, spacing=spacing, r=r, cap=cap,
@@ -540,13 +547,12 @@ def _build_lattice_impl(z: Array, *, spacing: float, r: int,
                         cap: int) -> Lattice:
     n, d = z.shape
     keys, weights = simplex_embed(z, spacing)  # (n, d+1, d+1), (n, d+1)
-    flat = keys.reshape(n * (d + 1), d + 1)
     big = n * (d + 1)
 
     # ---- exact dedup via lexicographic sort over PACKED keys ---------------
     # Packing is lossless (C2), so the only payload is the permutation; the
     # sorted coordinates come back out of the packed key columns themselves.
-    cols = _pack_key_cols(flat)
+    cols = _pack_key_cols(keys)
     payload = jnp.arange(big, dtype=jnp.int32)
     sorted_cols, (perm,) = _lex_sort(cols, [payload])
     spacked = jnp.stack(sorted_cols, axis=1)
@@ -557,7 +563,7 @@ def _build_lattice_impl(z: Array, *, spacing: float, r: int,
     ])
     uid_sorted = jnp.cumsum(new_group.astype(jnp.int32)) - 1  # (big,)
     m = uid_sorted[-1] + 1
-    pack_ovf = _pack_overflow(flat)
+    pack_ovf = _pack_overflow(keys)
     overflow = (m > cap) | pack_ovf
     slot_sorted = jnp.minimum(uid_sorted, cap)  # overflowed uniques -> dump row
 
@@ -595,8 +601,12 @@ def _neighbor_queries(coords: Array, valid: Array, *, d: int, r: int,
     Shared by BOTH build paths (sort merge-lookup and hash lookup) and by
     the build benchmark's phase breakdown, so the query grid — offsets,
     flattening order, validity masking — can never desynchronize between
-    the oracle and the fast path. Returns:
-      q_packed:  ((d+1)(cap+1)(2r), npk) int32 packed query keys;
+    the oracle and the fast path. Queries are ordered (direction, step,
+    slot) with the slot fastest: on the TPU, flattening a grid whose
+    minor axis is the 2r steps is a relayout whose compile time grows
+    with cap, so ``_neighbor_layout`` moves the steps minor only after
+    the lookup, on the (d+1, 2r, cap+1) result. Returns:
+      q_packed:  ((d+1)(2r)(cap+1), npk) int32 packed query keys;
       src_valid: same leading shape, bool — whether the SOURCE slot of
         each query is a valid lattice point (invalid sources must miss).
     """
@@ -605,15 +615,18 @@ def _neighbor_queries(coords: Array, valid: Array, *, d: int, r: int,
     dirs = (d + 1) * eye - 1  # (d+1, d+1): dirs[a] = offset of +1 step along a
 
     steps = jnp.concatenate([jnp.arange(-r, 0), jnp.arange(1, r + 1)])  # (2r,)
-    # queries[a, p, s] = coords[p] + steps[s] * dirs[a]
+    # queries[a, s, p] = coords[p] + steps[s] * dirs[a]: (d+1, 2r, cap+1, d+1)
     table = coords[: cap + 1]  # includes dump row; masked via src_valid
-    q = (table[None, :, None, :]
-         + steps[None, None, :, None] * dirs[:, None, None, :])  # (d+1, cap+1, 2r, d+1)
-    nq = (d + 1) * (cap + 1) * (2 * r)
-    q_packed = jnp.stack(_pack_key_cols(q.reshape(nq, d + 1)), axis=1)
-    src_valid = jnp.repeat(valid[: cap + 1], 2 * r)  # reshape order per a
-    src_valid = jnp.tile(src_valid, d + 1)
+    q = (table[None, None, :, :]
+         + steps[None, :, None, None] * dirs[:, None, None, :])
+    q_packed = jnp.stack(_pack_key_cols(q), axis=1)
+    src_valid = jnp.tile(valid[: cap + 1], (d + 1) * 2 * r)
     return q_packed, src_valid
+
+
+def _neighbor_layout(found: Array, *, d: int, r: int, cap: int) -> Array:
+    """Per-query results in ``_neighbor_queries`` order -> (d+1, cap+1, 2r)."""
+    return jnp.transpose(found.reshape(d + 1, 2 * r, cap + 1), (0, 2, 1))
 
 
 def _neighbor_table(coords: Array, valid: Array, *, d: int, r: int,
@@ -663,7 +676,7 @@ def _neighbor_table(coords: Array, valid: Array, *, d: int, r: int,
     out = jnp.full((nq,), cap, jnp.int32).at[
         jnp.where(is_q, spayload, nq)
     ].set(matched_slot, mode="drop")
-    return out.reshape(d + 1, cap + 1, 2 * r)
+    return _neighbor_layout(out, d=d, r=r, cap=cap)
 
 
 # ---------------------------------------------------------------------------
@@ -760,8 +773,7 @@ def _build_lattice_hash_impl(z: Array, *, spacing: float, r: int, cap: int,
     n, d = z.shape
     keys, weights = simplex_embed(z, spacing)  # (n, d+1, d+1), (n, d+1)
     big = n * (d + 1)
-    flat = keys.reshape(big, d + 1)
-    packed = jnp.stack(_pack_key_cols(flat), axis=1)  # (big, npk)
+    packed = jnp.stack(_pack_key_cols(keys), axis=1)  # (big, npk)
     hcap = hash_ops.hash_capacity(cap)
 
     # ---- dedup via hash insert --------------------------------------------
@@ -772,7 +784,7 @@ def _build_lattice_hash_impl(z: Array, *, spacing: float, r: int, cap: int,
     dense = jnp.cumsum(occ.astype(jnp.int32)) - 1  # hash slot -> dense id
     dense_of = jnp.where(occ, jnp.minimum(dense, cap), cap)
     tkeys = hash_ops.table_keys(owner, packed)  # (hcap, npk), empty -> SENT
-    pack_ovf = _pack_overflow(flat)
+    pack_ovf = _pack_overflow(keys)
     overflow = (m > cap) | ~jnp.all(row_ok) | pack_ovf
 
     # per-(input, vertex) slot ids, already in original order (no perm)
@@ -801,9 +813,10 @@ def _build_lattice_hash_impl(z: Array, *, spacing: float, r: int, cap: int,
     q_packed, src_valid = _neighbor_queries(coords, valid, d=d, r=r, cap=cap)
     hres = hash_ops.hash_lookup(tkeys, q_packed, src_valid, hcap,
                                 backend=backend)
-    nbr = jnp.where(src_valid & (hres >= 0),
-                    dense_of[jnp.clip(hres, 0, hcap - 1)],
-                    cap).reshape(d + 1, cap + 1, 2 * r)
+    nbr = _neighbor_layout(
+        jnp.where(src_valid & (hres >= 0),
+                  dense_of[jnp.clip(hres, 0, hcap - 1)], cap),
+        d=d, r=r, cap=cap)
 
     return Lattice(coords=coords, valid=valid, m=m, seg_ids=seg_ids,
                    weights=weights, nbr=nbr, overflow=overflow,
@@ -817,15 +830,38 @@ def _build_lattice_hash_impl(z: Array, *, spacing: float, r: int, cap: int,
 # ---------------------------------------------------------------------------
 
 
+# Splat and slice touch the d+1 vertices of each point one vertex at a
+# time: materializing all n(d+1) contributions as an (n, d+1, c) array and
+# reshaping it to rows is a TPU relayout whose compile time grows with n
+# (78 s for splat, 17 s for slice at protein's training size, against 18 s
+# and 2 s this way).
+
+
+def splat_rows(v: Array, seg: Array, weights: Array, rows: int) -> Array:
+    """Scatter-add ``weights[:, k] * v`` into rows ``seg[:, k]`` for every
+    vertex k: (n, c) values, (n, d+1) slots and weights -> (rows, c)."""
+    out = jnp.zeros((rows, v.shape[1]), v.dtype)
+    for k in range(seg.shape[1]):
+        out = out.at[seg[:, k]].add(weights[:, k:k + 1] * v)
+    return out
+
+
+def slice_rows(vals: Array, seg: Array, weights: Array) -> Array:
+    """Barycentric contraction ``sum_k weights[:, k] * vals[seg[:, k]]``:
+    (rows, c) table, (n, d+1) slots and weights -> (n, c)."""
+    out = weights[:, :1] * vals[seg[:, 0]]
+    for k in range(1, seg.shape[1]):
+        out = out + weights[:, k:k + 1] * vals[seg[:, k]]
+    return out
+
+
 def splat(lat: Lattice, v: Array) -> Array:
     """W^T v: scatter barycentric-weighted values onto lattice points.
 
     v: (n, c) -> (cap+1, c); dump row forced to zero.
     """
-    n, c = v.shape
-    contrib = (lat.weights[:, :, None] * v[:, None, :]).reshape(
-        n * (lat.d + 1), c)
-    out = jax.ops.segment_sum(contrib, lat.seg_ids, num_segments=lat.cap + 1)
+    out = splat_rows(v, lat.seg_ids.reshape(lat.n, lat.d + 1), lat.weights,
+                     lat.cap + 1)
     return out.at[lat.cap].set(0.0)
 
 
@@ -888,9 +924,8 @@ def blur(lat: Lattice, vals: Array, stencil: Array, *,
 
 def slice_(lat: Lattice, vals: Array) -> Array:
     """W u: barycentric resampling back at the input locations. -> (n, c)"""
-    per_vertex = vals[lat.seg_ids]  # (n*(d+1), c)
-    per_vertex = per_vertex.reshape(lat.n, lat.d + 1, -1)
-    return jnp.einsum("nkc,nk->nc", per_vertex, lat.weights)
+    return slice_rows(vals, lat.seg_ids.reshape(lat.n, lat.d + 1),
+                      lat.weights)
 
 
 # ---------------------------------------------------------------------------

@@ -15,6 +15,7 @@ published decimal values; see DESIGN.md §5.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import NamedTuple
 
 import numpy as np
@@ -91,7 +92,9 @@ def load(name: str, *, scale: float = 1.0, seed: int = 0) -> Dataset:
     spec = SPECS[name]
     n = max(int(spec["n"] * scale), 64)
     d = spec["d"]
-    rng = np.random.default_rng(seed + hash(name) % (2 ** 31))
+    # crc32, not hash(): str hashes are salted per process, so the same
+    # seed would give different data in every run
+    rng = np.random.default_rng(seed + zlib.crc32(name.encode()))
     x = _inputs(rng, n, d, spec)
     y = _targets(rng, x)
 

@@ -65,13 +65,13 @@ class SimplexGPConfig:
     cg_tol_eval: float = 1e-2
     max_cg_iters: int = 100
     # lattice-MVM backend tier (kernels/blur/ops.py policy; DESIGN.md §8):
-    # "auto" picks fused_pallas/per_direction_pallas on TPU by VMEM fit and
-    # the fused single-jit XLA path elsewhere.
+    # "auto" picks the fused single-jit XLA path on every host (the Pallas
+    # tiers do not compile for the TPU yet).
     backend: str = "auto"
     # lattice BUILD path (kernels/hash/ops.py policy; DESIGN.md §11):
-    # "auto" resolves to the open-addressing hash build (hash_pallas on
-    # TPU when the key table fits VMEM, hash_xla elsewhere); "sort" keeps
-    # the original lexicographic-sort build as the bit-exact oracle.
+    # "auto" resolves to the open-addressing hash build (hash_xla on every
+    # host); "sort" keeps the original lexicographic-sort build as the
+    # bit-exact oracle.
     build_backend: str = "auto"
     precond_rank: int = 0  # 0 = no preconditioner (lattice MVMs are cheap)
     num_probes: int = 8
@@ -95,10 +95,9 @@ class SimplexGPConfig:
     # describe the preconditioned operator, not K_hat).
     logdet_estimator: str = "cg"
     # frozen-lattice serving (gp/serve.py; DESIGN.md §12): the query-path
-    # backend (kernels/slice/ops.py policy — "auto" fuses lookup + slice
-    # into one Pallas kernel on TPU when the frozen state fits VMEM) and
-    # the fixed padding-bucket sizes jit compiles per (not per batch
-    # shape).
+    # backend (kernels/slice/ops.py policy — "auto" resolves to the XLA
+    # lookup + slice on every host) and the fixed padding-bucket sizes jit
+    # compiles per (not per batch shape).
     serve_backend: str = "auto"
     serve_buckets: tuple[int, ...] = (64, 256, 1024, 4096)
 

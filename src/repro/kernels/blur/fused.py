@@ -45,10 +45,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _shift_down(x: Array, s: int) -> Array:
     """Shift rows down by s, zero-filling the top (static s)."""
@@ -165,7 +161,7 @@ def fused_filter_pallas(lat, v: Array, taps: tuple[float, ...], *,
             pltpu.VMEM((cap1, c), v.dtype),  # work
             pltpu.VMEM((cap1, c), v.dtype),  # accum
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(

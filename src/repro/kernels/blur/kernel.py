@@ -42,10 +42,6 @@ Array = jax.Array
 
 DEFAULT_BLOCK_P = 1024
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _blur_kernel(vals_ref, nbr_ref, out_ref, *, taps: tuple[float, ...],
                  dump_row: int, block_p: int):
@@ -95,7 +91,7 @@ def blur_direction_pallas(vals: Array, nbr_dir: Array,
         ],
         out_specs=pl.BlockSpec((block_p, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, c), vals.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(vals, nbr_dir)
@@ -171,7 +167,7 @@ def blur_direction_blocked_pallas(vals: Array, nbr_dir: Array,
         ],
         out_specs=pl.BlockSpec((block_p, c), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, c), vals.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(vals, nbr_dir)

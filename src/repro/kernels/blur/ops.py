@@ -1,27 +1,27 @@
 """Public lattice-MVM ops: backend policy + dispatch for the full operator.
 
-Backend tiers (DESIGN.md §8), chosen from (n, cap, d, r, c) and platform:
+Backend tiers (DESIGN.md §8):
 
   fused_pallas         one pallas_call for splat -> 2(d+1) sweeps -> slice;
                        the value table lives in VMEM scratch throughout
-                       (fused.py). Engaged on TPU when every resident
-                       buffer fits the VMEM budget.
+                       (fused.py). Runs in interpret mode only: Mosaic
+                       does not compile it for the TPU.
   per_direction_pallas one pallas_call per directional sweep (kernel.py),
                        XLA splat/slice around them. Resident gather source
                        when the table fits; grid-blocked streaming variant
-                       for moderately oversized tables.
+                       for moderately oversized tables. Interpret mode
+                       only, like fused_pallas.
   fused_xla            single-jit XLA composition with the scatter-free
-                       sorted-segment splat (lattice.splat_sorted) — the
-                       fast path on hosts without a TPU, and the same
-                       algorithm the fused kernel runs in VMEM.
-  xla                  the legacy reference composition (segment_sum splat
+                       sorted-segment splat (lattice.splat_sorted) — what
+                       ``auto`` resolves to on every host, the TPU
+                       included.
+  xla                  the legacy reference composition (scatter-add splat
                        + scan blur + slice). Keeps the seed semantics;
                        always available, any table size, traced weights OK.
 
-``auto`` resolves per the table above. Pallas tiers need CONCRETE stencil
-taps (they are baked into the kernel); pass them via ``taps=`` (e.g. from
-``FilterSpec`` / ``Stencil.weights``) — ``auto`` falls back to the XLA tier
-when only traced weights are available rather than crash under jit.
+Pallas tiers need CONCRETE stencil taps (they are baked into the
+kernel); pass them via ``taps=`` (e.g. from ``FilterSpec`` /
+``Stencil.weights``).
 """
 from __future__ import annotations
 
@@ -152,29 +152,20 @@ def autotune_block_p(lat: Lattice, c: int, taps: tuple[float, ...], *,
     return best
 
 
-def choose_backend(*, n: int, d: int, r: int, cap1: int, c: int,
-                   taps_available: bool = True,
-                   platform: str | None = None) -> str:
-    """Resolve ``auto`` to a concrete backend for this problem + host."""
-    platform = platform or jax.default_backend()
-    if not taps_available:
-        # only the Pallas tiers bake taps into the kernel; the fused XLA
-        # tier (scatter-free sorted splat) takes traced weights fine
-        return "fused_xla"
-    if platform == "tpu":
-        if fits_vmem(n, d, r, cap1, c):
-            return "fused_pallas"
-        if table_fits_vmem(cap1, c):
-            return "per_direction_pallas"
-        # past the resident budget the blocked streaming kernel re-reads
-        # the table once per block_p-row source tile — traffic that always
-        # loses to the XLA gather at these sizes — so the policy prefers
-        # fused_xla; the blocked variant stays reachable explicitly via
-        # backend="per_direction_pallas" for strictly-VMEM-bound runs.
-        return "fused_xla"
-    # CPU/GPU hosts: the fused idea lands as one jitted XLA program with
-    # the scatter-free splat; Pallas runs only under explicit interpret.
-    return "fused_xla"
+def resolve_backend(backend: str) -> str:
+    """The concrete backend a ``lattice_mvm`` call runs.
+
+    ``auto`` is ``fused_xla`` on every host, the TPU included: the TPU
+    kernel compiler (Mosaic) lowers neither Pallas tier. Their vector row
+    gather from a VMEM-resident table (``jnp.take(ref[...], idx, axis=0)``)
+    is refused with "Shape mismatch in input, indices and output"
+    (tests/test_tpu_compile.py). They stay reachable through an explicit
+    ``backend=``, which on a TPU raises that compiler error.
+    """
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown backend {backend!r}; want one of "
+                         f"{BACKENDS}")
+    return "fused_xla" if backend == "auto" else backend
 
 
 # ---------------------------------------------------------------------------
@@ -289,9 +280,7 @@ def lattice_mvm(lat: Lattice, v: Array, weights: Array | None = None, *,
     replicated, and the whole MVM costs ONE psum. The per-device compute is
     plain XLA, so ``backend`` is ignored on that path.
     """
-    if backend not in BACKENDS:
-        raise ValueError(f"unknown backend {backend!r}; want one of "
-                         f"{BACKENDS}")
+    backend = resolve_backend(backend)
     if weights is None and taps is None:
         raise ValueError("lattice_mvm needs a stencil: pass weights= "
                          "(array) and/or taps= (concrete tuple)")
@@ -304,10 +293,6 @@ def lattice_mvm(lat: Lattice, v: Array, weights: Array | None = None, *,
                                    symmetrize=symmetrize,
                                    transpose=transpose)
     concrete = _concrete_taps(weights, taps)
-    if backend == "auto":
-        backend = choose_backend(n=lat.n, d=lat.d, r=lat.r, cap1=lat.cap + 1,
-                                 c=v.shape[1],
-                                 taps_available=concrete is not None)
     if backend in ("fused_pallas", "per_direction_pallas") and concrete is None:
         raise ValueError(
             f"backend {backend!r} needs concrete stencil taps; pass taps= "

@@ -27,9 +27,6 @@ from repro.core.kernels_math import KernelProfile
 
 Array = jax.Array
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_M = 256
@@ -83,7 +80,7 @@ def exact_mvm_pallas(profile: KernelProfile, x: Array, v: Array, *,
         ],
         out_specs=pl.BlockSpec((block_n, c), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, c), v.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, x, v)

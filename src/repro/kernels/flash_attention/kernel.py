@@ -28,9 +28,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 256
@@ -144,7 +141,7 @@ def flash_attention_pallas(q: Array, k: Array, v: Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

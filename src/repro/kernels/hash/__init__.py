@@ -7,10 +7,9 @@ same design the paper's CUDA implementation uses. ops.py carries the
 backend policy (hash_pallas / hash_xla, with "sort" as the oracle tier
 kept in core/lattice.py).
 """
-from repro.kernels.hash.ops import (BUILD_BACKENDS, choose_build_backend,
-                                    hash_capacity, hash_insert, hash_lookup,
+from repro.kernels.hash.ops import (BUILD_BACKENDS, hash_capacity,
+                                    hash_insert, hash_lookup,
                                     resolve_build_backend, table_keys)
 
-__all__ = ["BUILD_BACKENDS", "choose_build_backend", "hash_capacity",
-           "hash_insert", "hash_lookup", "resolve_build_backend",
-           "table_keys"]
+__all__ = ["BUILD_BACKENDS", "hash_capacity", "hash_insert", "hash_lookup",
+           "resolve_build_backend", "table_keys"]

@@ -41,10 +41,6 @@ Array = jax.Array
 
 DEFAULT_BLOCK_Q = 1024
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 # ---------------------------------------------------------------------------
 # Vectorized lookup.
@@ -109,7 +105,8 @@ def hash_lookup_pallas(tkeys: Array, queries: Array, active: Array, *,
         ],
         out_specs=pl.BlockSpec((block_q, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, 1), jnp.int32),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(tkeys, queries, h0, act)
     return out[:nq, 0]

@@ -1,18 +1,17 @@
 """Build-backend policy + dispatch for the lattice hash table.
 
 Mirrors the blur MVM policy (kernels/blur/ops.py): ``auto`` resolves to a
-concrete backend from the platform and the table's VMEM footprint, every
-tier stays explicitly reachable, and off-TPU the Pallas kernels dispatch
-to the XLA fallback unless the interpreter is requested.
+concrete backend, every tier stays explicitly reachable, and off-TPU the
+Pallas kernels dispatch to the XLA fallback unless the interpreter is
+requested.
 
 Backend tiers (DESIGN.md §11):
 
   hash_pallas  accelerator-resident table: sequential-core insert +
-               vectorized resident-table lookup (kernel.py). Engaged on
-               TPU when the key table fits the VMEM budget.
+               vectorized resident-table lookup (kernel.py). Interpret
+               mode only: Mosaic does not compile either kernel.
   hash_xla     epoch-based scatter-min insert + while-loop probe lookup
-               (ref.py) — the fast path everywhere else, and the TPU
-               fallback for oversized tables.
+               (ref.py) — what ``auto`` resolves to on every host.
   sort         the original two-pass lexicographic-sort build
                (core/lattice._build_lattice_impl). Bit-exact oracle: the
                hash backends must match it up to slot permutation.
@@ -25,7 +24,6 @@ lex-ordered reference.
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.hash import ref
 from repro.kernels.hash.kernel import hash_insert_pallas, hash_lookup_pallas
@@ -35,11 +33,6 @@ from repro.kernels.hash.ref import (hash_insert_xla, hash_lookup_xla,
 Array = jax.Array
 
 BUILD_BACKENDS = ("auto", "hash_pallas", "hash_xla", "sort")
-
-# VMEM budget for keeping the key table resident in the lookup kernel
-# (same ceiling discipline as kernels/blur/ops.py).
-TABLE_BUDGET_BYTES = 8 * 1024 * 1024
-
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
@@ -52,28 +45,20 @@ def hash_capacity(cap: int) -> int:
     return 2 * (1 << (cap - 1).bit_length())
 
 
-def table_vmem_bytes(hcap: int, npk: int, itemsize: int = 4) -> int:
-    return hcap * npk * itemsize
+def resolve_build_backend(backend: str) -> str:
+    """The concrete build backend a ``build_lattice`` call runs.
 
-
-def choose_build_backend(*, hcap: int, npk: int,
-                         platform: str | None = None) -> str:
-    """Resolve ``auto`` to a concrete build backend for this problem/host."""
-    platform = platform or jax.default_backend()
-    if platform == "tpu" and \
-            table_vmem_bytes(hcap, npk) <= TABLE_BUDGET_BYTES:
-        return "hash_pallas"
-    return "hash_xla"
-
-
-def resolve_build_backend(backend: str, *, hcap: int = 0,
-                          npk: int = 1) -> str:
+    ``auto`` is ``hash_xla`` on every host, the TPU included: Mosaic
+    compiles neither Pallas kernel. The lookup's vector row gather from the
+    resident key table is refused with "Shape mismatch in input, indices
+    and output", and the insert's scalar stores with "Cannot store scalars
+    to VMEM" (tests/test_tpu_compile.py). ``hash_pallas`` stays reachable
+    explicitly.
+    """
     if backend not in BUILD_BACKENDS:
         raise ValueError(f"unknown build backend {backend!r}; want one of "
                          f"{BUILD_BACKENDS}")
-    if backend == "auto":
-        return choose_build_backend(hcap=hcap, npk=npk)
-    return backend
+    return "hash_xla" if backend == "auto" else backend
 
 
 def hash_insert(packed: Array, hcap: int, *, backend: str = "hash_xla",
@@ -103,6 +88,5 @@ def hash_lookup(tkeys: Array, queries: Array, active: Array, hcap: int, *,
     return hash_lookup_xla(tkeys, queries, active, hcap)
 
 
-__all__ = ["BUILD_BACKENDS", "choose_build_backend", "resolve_build_backend",
-           "hash_capacity", "hash_insert", "hash_lookup", "table_keys",
-           "table_vmem_bytes", "ref"]
+__all__ = ["BUILD_BACKENDS", "resolve_build_backend", "hash_capacity",
+           "hash_insert", "hash_lookup", "table_keys", "ref"]

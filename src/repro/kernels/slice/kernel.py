@@ -36,10 +36,6 @@ Array = jax.Array
 
 DEFAULT_BLOCK_B = 256
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _slice_kernel(tk_ref, s2r_ref, tab_ref, q_ref, h_ref, act_ref, w_ref,
                   out_ref, miss_ref, *, hcap: int, m: int, dp1: int,
@@ -198,7 +194,8 @@ def slice_query_tangent_pallas(tkeys: Array, row_of_slot: Array,
             jax.ShapeDtypeStruct((padded, c), tables.dtype),
             jax.ShapeDtypeStruct((padded, 1), tables.dtype),
         ),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(tkeys, row_of_slot.reshape(-1, 1), tables, q_packed, h0, act,
       weights, weights_dot)
@@ -248,7 +245,8 @@ def slice_query_pallas(tkeys: Array, row_of_slot: Array, tables: Array,
             jax.ShapeDtypeStruct((padded, c), tables.dtype),
             jax.ShapeDtypeStruct((padded, 1), tables.dtype),
         ),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(tkeys, row_of_slot.reshape(-1, 1), tables, q_packed, h0, act,
       weights)

@@ -1,20 +1,19 @@
 """Serving-slice policy + dispatch for frozen-table queries (DESIGN.md §12).
 
 Mirrors the blur (kernels/blur/ops.py) and build (kernels/hash/ops.py)
-policies: ``auto`` resolves from the platform and the frozen state's VMEM
-footprint, every tier stays explicitly reachable, and off-TPU the Pallas
-kernel dispatches to the XLA fallback unless the interpreter is
-requested.
+policies: ``auto`` resolves to a concrete backend, every tier stays
+explicitly reachable, and off-TPU the Pallas kernel dispatches to the XLA
+fallback unless the interpreter is requested.
 
 Backend tiers:
 
   slice_pallas  one fused pallas_call per query batch: hash probe +
                 dense-row translation + table gather + barycentric
                 contraction with tkeys/row_of_slot/tables VMEM-resident
-                (kernel.py). Engaged on TPU when the frozen state fits
-                the VMEM budget.
+                (kernel.py). Interpret mode only: Mosaic does not
+                compile it for the TPU.
   slice_xla     hash lookup (kernels/hash/ref.py) + gather + einsum —
-                the fallback everywhere else and for oversized tables.
+                what ``auto`` resolves to on every host.
 """
 from __future__ import annotations
 
@@ -31,39 +30,23 @@ Array = jax.Array
 
 SLICE_BACKENDS = ("auto", "slice_pallas", "slice_xla")
 
-# VMEM budget for the resident frozen state (key table + row map + value
-# tables), same ceiling discipline as the other kernel policies.
-SERVE_BUDGET_BYTES = 10 * 1024 * 1024
-
-
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def frozen_vmem_bytes(hcap: int, npk: int, m1: int, c: int,
-                      itemsize: int = 4) -> int:
-    """Resident bytes of the fused query kernel's frozen state."""
-    return itemsize * (hcap * npk + hcap + m1 * c)
+def resolve_slice_backend(backend: str) -> str:
+    """The concrete backend a frozen-table query runs.
 
-
-def choose_slice_backend(*, hcap: int, npk: int, m1: int, c: int,
-                         platform: str | None = None) -> str:
-    """Resolve ``auto`` to a concrete serving backend for this host."""
-    platform = platform or jax.default_backend()
-    if platform == "tpu" and \
-            frozen_vmem_bytes(hcap, npk, m1, c) <= SERVE_BUDGET_BYTES:
-        return "slice_pallas"
-    return "slice_xla"
-
-
-def resolve_slice_backend(backend: str, *, hcap: int = 0, npk: int = 1,
-                          m1: int = 1, c: int = 1) -> str:
+    ``auto`` is ``slice_xla`` on every host, the TPU included: Mosaic
+    refuses the fused kernel's probe gather from the resident key table
+    with "Shape mismatch in input, indices and output"
+    (tests/test_tpu_compile.py). ``slice_pallas`` stays reachable
+    explicitly.
+    """
     if backend not in SLICE_BACKENDS:
         raise ValueError(f"unknown slice backend {backend!r}; want one of "
                          f"{SLICE_BACKENDS}")
-    if backend == "auto":
-        return choose_slice_backend(hcap=hcap, npk=npk, m1=m1, c=c)
-    return backend
+    return "slice_xla" if backend == "auto" else backend
 
 
 def slice_query(index: LatticeIndex, tables: Array, q_packed: Array,
@@ -77,9 +60,7 @@ def slice_query(index: LatticeIndex, tables: Array, q_packed: Array,
     queries). Misses contribute zero and their barycentric mass comes
     back as the per-query slice-miss diagnostic.
     """
-    m1, c = tables.shape
-    resolved = resolve_slice_backend(backend, hcap=index.hcap,
-                                     npk=index.tkeys.shape[1], m1=m1, c=c)
+    resolved = resolve_slice_backend(backend)
     if resolved == "slice_pallas":
         run_interp = interpret if interpret is not None else False
         if _on_tpu() or run_interp:
@@ -106,9 +87,7 @@ def slice_query_tangent(index: LatticeIndex, tables: Array, q_packed: Array,
     probe loop once and both contractions in-register; everywhere else
     the XLA reference gathers once and einsums twice.
     """
-    m1, c = tables.shape
-    resolved = resolve_slice_backend(backend, hcap=index.hcap,
-                                     npk=index.tkeys.shape[1], m1=m1, c=c)
+    resolved = resolve_slice_backend(backend)
     if resolved == "slice_pallas":
         run_interp = interpret if interpret is not None else False
         if _on_tpu() or run_interp:
@@ -136,6 +115,5 @@ def slice_query_jacobian(index: LatticeIndex, tables: Array, q_packed: Array,
                                     index.hcap)
 
 
-__all__ = ["SLICE_BACKENDS", "SERVE_BUDGET_BYTES", "choose_slice_backend",
-           "resolve_slice_backend", "frozen_vmem_bytes", "slice_query",
+__all__ = ["SLICE_BACKENDS", "resolve_slice_backend", "slice_query",
            "slice_query_tangent", "slice_query_jacobian"]

@@ -43,16 +43,13 @@ bit-compatible modulo f32 summation order with the single-device
 from __future__ import annotations
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # graduated API (jax >= 0.5)
-    from jax import shard_map
-except ImportError:  # this image's jax 0.4.x only has the experimental path
-    from jax.experimental.shard_map import shard_map
-
-from repro.core.lattice import Lattice
+from repro.core.lattice import Lattice, slice_rows, splat_rows
 
 Array = jax.Array
 
@@ -136,12 +133,8 @@ def sharded_lattice_mvm(lat: Lattice, v: Array, weights: Array | None = None,
             [bary, jnp.zeros((ghost, d1), bary.dtype)], axis=0)
 
     def local_mvm(v_loc, seg_loc, bw_loc, nbr, w):
-        nl = v_loc.shape[0]
-        seg_flat = seg_loc.reshape(nl * d1)
         # --- splat (local) + the ONE collective --------------------------
-        contrib = (bw_loc[:, :, None] * v_loc[:, None, :]).reshape(
-            nl * d1, c)
-        table = jax.ops.segment_sum(contrib, seg_flat, num_segments=cap + 1)
+        table = splat_rows(v_loc, seg_loc, bw_loc, cap + 1)
         table = jax.lax.psum(table, axis_name)
         table = table.at[cap].set(0.0)
 
@@ -160,8 +153,7 @@ def sharded_lattice_mvm(lat: Lattice, v: Array, weights: Array | None = None,
             blurred = 0.5 * (blurred + blurred_r)
 
         # --- slice (local) ----------------------------------------------
-        per_vertex = blurred[seg_flat].reshape(nl, d1, c)
-        return jnp.einsum("nkc,nk->nc", per_vertex, bw_loc)
+        return slice_rows(blurred, seg_loc, bw_loc)
 
     fn = shard_map(
         local_mvm, mesh=mesh,
@@ -253,11 +245,11 @@ def replicated_table_serve(fn, mesh: Mesh, axis_name: str = "data"):
     output sharded the same way. ``fn`` must be embarrassingly parallel
     over query rows (no cross-query reductions) — which is exactly what
     the frozen slice path is."""
-    # check_rep=False: the body's probe while_loop has no replication rule
-    # in this jax version; replication is by construction here (the frozen
-    # state is P() everywhere and nothing reduces across queries).
+    # check_vma=False: replication is by construction here (the frozen
+    # state is P() everywhere and nothing reduces across queries), so the
+    # varying-manual-axes type check has nothing to prove.
     sharded = shard_map(fn, mesh=mesh, in_specs=(P(), P(axis_name)),
-                        out_specs=P(axis_name), check_rep=False)
+                        out_specs=P(axis_name), check_vma=False)
     return jax.jit(sharded)
 
 
@@ -268,11 +260,12 @@ def replicated_table_serve(fn, mesh: Mesh, axis_name: str = "data"):
 COLLECTIVE_PRIMITIVES = ("psum", "all_gather", "all_to_all", "ppermute",
                          "psum_scatter")
 
-# inside shard_map bodies jax names the reduction primitive "psum2"
-# (the positional-semantics variant); count it as a psum — it IS the
-# cross-device all-reduce. "pbroadcast" is replication bookkeeping with
-# no communication and is deliberately not counted.
-_PRIMITIVE_ALIASES = {"psum2": "psum"}
+# inside shard_map bodies jax names the reduction primitive
+# "psum_invariant" (its result is the same on every device); count it as a
+# psum — it IS the cross-device all-reduce. "pvary" is replication
+# bookkeeping with no communication and is deliberately not counted.
+_PRIMITIVE_ALIASES = {"psum_invariant": "psum"}
+_JAXPR_TYPES = (jax.extend.core.Jaxpr, jax.extend.core.ClosedJaxpr)
 
 
 def count_primitive(jaxpr, name: str) -> int:
@@ -286,9 +279,8 @@ def count_primitive(jaxpr, name: str) -> int:
             total += 1
         for v in eqn.params.values():
             for sub in jax.tree_util.tree_leaves(
-                    v, is_leaf=lambda x: isinstance(
-                        x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                if isinstance(sub, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+                    v, is_leaf=lambda x: isinstance(x, _JAXPR_TYPES)):
+                if isinstance(sub, _JAXPR_TYPES):
                     total += count_primitive(sub, name)
     return total
 

@@ -5,6 +5,13 @@ import sys
 
 import numpy as np
 import pytest
+from hypothesis import settings
+
+# A property's first example pays the jit compile of what it checks, which
+# takes seconds and trips hypothesis's 200 ms default deadline; examples
+# are bounded by each property's max_examples instead.
+settings.register_profile("repro", deadline=None)
+settings.load_profile("repro")
 
 # NOTE: no XLA_FLAGS here — tests must see the 1 real CPU device.
 # Multi-device tests (marker ``multidevice``) run their sharded half in a

@@ -138,3 +138,19 @@ def test_trend_check_runs_clean():
     from benchmarks.trend_check import main
 
     assert main([]) == 0
+
+
+def test_run_exits_nonzero_when_a_module_raises(monkeypatch):
+    """benchmarks.run keeps going past a failed module but must not
+    report success: a run built on it would read "ok" over a path that
+    never ran."""
+    from benchmarks import run
+
+    class Broken:
+        @staticmethod
+        def main():
+            raise RuntimeError("module failed")
+
+    monkeypatch.setattr(run, "MODULES", {"broken": Broken})
+    monkeypatch.setattr(sys, "argv", ["run", "broken"])
+    assert run.main() == 1

@@ -19,12 +19,12 @@ import pathlib
 import sys
 import tempfile
 
+from hypothesis import given, settings, strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hyp_compat import given, settings, st
 from repro.gp import (GPParams, SimplexGP, SimplexGPConfig, fit, freeze,
                       load_predictor, save_predictor, self_probe,
                       PredictorLoadError)
@@ -116,9 +116,8 @@ def test_checkpoint_async_wait_then_verify(tmp_path, rng):
 def test_blob_roundtrip_property(dtype, rank, seed):
     """Raw blob layer: any shape/dtype leaf survives save+load exactly.
 
-    NOTE: no pytest fixtures here — @given properties (and the
-    _hyp_compat shim) require zero-fixture signatures, so temp dirs come
-    from tempfile."""
+    NOTE: no pytest fixtures here — a function-scoped fixture is not
+    reset between @given examples, so temp dirs come from tempfile."""
     rng = np.random.default_rng(seed)
     shape = tuple(int(rng.integers(1, 5)) for _ in range(rank))
     arr = (rng.normal(size=shape) * 100).astype(dtype)

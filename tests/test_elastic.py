@@ -23,13 +23,13 @@ import sys
 import tempfile
 import textwrap
 
+from hypothesis import given, settings, strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-from _hyp_compat import given, settings, st
 from repro.core import lattice as lat_mod
 from repro.core.filtering import LatticeCache
 from repro.core.stencil import make_stencil

@@ -1,9 +1,9 @@
 """Permutohedral lattice geometry invariants (paper §3.2)."""
+from hypothesis import given, settings, strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp_compat import given, settings, st
 
 from repro.core import lattice as L
 

@@ -303,11 +303,9 @@ def test_insert_full_table_reports_failure():
 def test_build_backend_policy():
     assert hash_ops.resolve_build_backend("sort") == "sort"
     assert hash_ops.resolve_build_backend("hash_xla") == "hash_xla"
-    resolved = hash_ops.resolve_build_backend("auto", hcap=1024, npk=2)
-    if jax.default_backend() == "tpu":
-        assert resolved == "hash_pallas"
-    else:
-        assert resolved == "hash_xla"
+    # the Pallas build does not compile for the TPU (test_tpu_compile.py),
+    # so auto resolves to the XLA tier on every host
+    assert hash_ops.resolve_build_backend("auto") == "hash_xla"
     with pytest.raises(ValueError):
         hash_ops.resolve_build_backend("bogus")
 
@@ -336,7 +334,7 @@ def test_lattice_cache_keys_on_build_backend(rng):
     assert cache.hits == 2
     # "auto" keys on its RESOLUTION: on this host it must HIT the
     # explicit hash entry, not build a duplicate lattice
-    resolved = hash_ops.resolve_build_backend("auto", hcap=512, npk=2)
+    resolved = hash_ops.resolve_build_backend("auto")
     lat_auto = cache.get(tag, x, build_backend="auto", **kw)
     if resolved == "hash_xla":
         assert lat_auto is lat_h

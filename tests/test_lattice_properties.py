@@ -1,7 +1,6 @@
 """Property tests for permutohedral-lattice invariants (hypothesis-style).
 
-Uses tests/_hyp_compat (real hypothesis when installed, deterministic
-replay otherwise). Four families from the build's contract:
+Four families from the build's contract:
 
   * barycentric weights are a valid simplex point (nonneg, sum to 1) and
     the vertex keys live on the lattice plane (coords sum to 0 mod d+1);
@@ -12,15 +11,10 @@ replay otherwise). Four families from the build's contract:
   * adversarial inputs raise the overflow/pack_overflow FLAGS instead of
     silently corrupting the table.
 """
-import sys
-import os
-
-sys.path.insert(0, os.path.dirname(__file__))
-
+from hypothesis import given, settings, strategies as st
 import jax.numpy as jnp
 import numpy as np
 
-from _hyp_compat import given, settings, st
 from repro.core import lattice as lat_mod
 from repro.core.stencil import make_stencil
 

@@ -150,10 +150,7 @@ def test_replicated_mesh_serving_zero_collectives(rng):
     """The DESIGN.md §12 serving contract: frozen tables replicated,
     queries sharded, ZERO collectives on the jaxpr, and results identical
     to single-device serving."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     _, _, x, _, _, pred = _frozen(rng, n=300)
@@ -166,7 +163,7 @@ def test_replicated_mesh_serving_zero_collectives(rng):
 
     fn = shard_map(functools.partial(_predict_core, backend="slice_xla"),
                    mesh=mesh, in_specs=(P(), P("data")),
-                   out_specs=P("data"), check_rep=False)
+                   out_specs=P("data"), check_vma=False)
     counts = collective_counts(fn, pred, jnp.zeros((64, 3), jnp.float32))
     assert all(v == 0 for v in counts.values()), counts
 

@@ -29,17 +29,13 @@ The gradcheck suite behind the PR's query-space-gradient contract:
 CI runs this file as its own lane: ``pytest -m gradcheck``.
 """
 import functools
-import os
-import sys
 
-sys.path.insert(0, os.path.dirname(__file__))
-
+from hypothesis import given, settings, strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hyp_compat import given, settings, st
 from repro.core import filtering
 from repro.core import lattice as L
 from repro.core.kernels_math import PROFILES
@@ -405,10 +401,7 @@ def test_query_gradients_zero_collective():
     adds NO collectives — the table cotangent is partial-evaluated away
     (grad is taken w.r.t. the sharded queries only), so the gradient
     jaxpr is as collective-free as the forward serving jaxpr."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     _, _, x, _, pred = _frozen(3)
@@ -420,13 +413,13 @@ def test_query_gradients_zero_collective():
         return jax.grad(f)(q)
 
     fn = shard_map(grad_core, mesh=mesh, in_specs=(P(), P("data")),
-                   out_specs=P("data"), check_rep=False)
+                   out_specs=P("data"), check_vma=False)
     counts = collective_counts(fn, pred, jnp.zeros((64, 3), jnp.float32))
     assert all(v == 0 for v in counts.values()), counts
     # the fused analytic pass is likewise collective-free
     fn2 = shard_map(lambda p, q: predict_grad(p, q).dmean, mesh=mesh,
                     in_specs=(P(), P("data")), out_specs=P("data"),
-                    check_rep=False)
+                    check_vma=False)
     counts2 = collective_counts(fn2, pred,
                                 jnp.zeros((64, 3), jnp.float32))
     assert all(v == 0 for v in counts2.values()), counts2

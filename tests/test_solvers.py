@@ -1,9 +1,9 @@
 """Krylov solver tests: CG, preconditioning, SLQ, RR-CG."""
+from hypothesis import given, settings, strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp_compat import given, settings, st
 
 from repro.core import kernels_math as km
 from repro.solvers import (cg, cg_while, expected_iters, lanczos,

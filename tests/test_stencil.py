@@ -1,7 +1,7 @@
 """Stencil discretization tests (paper §4.1, Eq. 9)."""
 import numpy as np
 import pytest
-from _hyp_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.kernels_math import PROFILES, get_profile
 from repro.core.stencil import _coverage_curves, make_stencil, solve_spacing
