@@ -11,9 +11,9 @@ per size:
   * the collective count of one sharded MVM from its jaxpr (contract:
     exactly ONE psum, nothing else — DESIGN.md §10);
   * the multi-RHS mBCG contract: a [y | Z] block with k probes traces
-    ONE batched lattice MVM per CG iteration (``ops.mvm_count`` /
-    ``mvm_cols`` instrumentation), and the batched block solve is raced
-    against the k+1 per-column solves it replaces.
+    ONE batched lattice MVM per CG iteration (the ``mvm.calls`` and
+    ``mvm.cols`` counters of ``repro.obs``), and the batched block solve
+    is raced against the k+1 per-column solves it replaces.
 
 On a CPU host the 8 "devices" share the physical cores, so sharded wall
 time measures overhead, not speedup — the artifact records it honestly
@@ -39,9 +39,10 @@ def _worker() -> None:
     import numpy as np
 
     from benchmarks.common import SCALE
+    from repro import obs
     from repro.core import filtering, lattice as lat_mod
     from repro.core.stencil import make_stencil
-    from repro.kernels.blur.ops import lattice_mvm, mvm_cols, mvm_count
+    from repro.kernels.blur.ops import lattice_mvm
     from repro.sharding import simplex as sx
     from repro.solvers.cg import cg
 
@@ -83,9 +84,10 @@ def _worker() -> None:
         matvec, _ = filtering.mvm_operator(z, st, cap=lat.cap)
         op = lambda vv: matvec(vv) + 0.1 * vv
         b = jnp.asarray(rng.normal(size=(n, 1 + k)), jnp.float32)
-        c0, w0 = mvm_count(), mvm_cols()
+        c0, w0 = obs.counter("mvm.calls"), obs.counter("mvm.cols")
         cg(op, b, tol=1e-2, max_iters=20)
-        traced_mvms, traced_cols = mvm_count() - c0, mvm_cols() - w0
+        traced_mvms = obs.counter("mvm.calls") - c0
+        traced_cols = obs.counter("mvm.cols") - w0
         t_block = timeit(lambda bb: cg(op, bb, tol=1e-2, max_iters=20)[0], b)
         t_cols = timeit(lambda bb: [
             cg(op, bb[:, i:i + 1], tol=1e-2, max_iters=20)[0]

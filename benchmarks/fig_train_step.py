@@ -11,9 +11,9 @@ This benchmark races both pipelines on the same data — the "legacy" config
 (``shared_lattice=False, logdet_estimator="slq"``) IS the pre-change
 measurement, recorded in the same artifact — and reports:
 
-  * builds/step and builds/posterior (counted at trace level via
-    ``lattice.build_count``: each traced build is one construction in the
-    compiled program);
+  * builds/step and builds/posterior (counted at trace level by the
+    ``lattice.builds`` counter of ``repro.obs``: each traced build is one
+    construction in the compiled program);
   * median step / posterior wall seconds;
   * MLL value under the CG-reused log-det vs the separate-SLQ one, as
     multi-seed means/stds at a converged CG tolerance — both are stochastic
@@ -36,7 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import SCALE, emit, timeit, write_json
-from repro.core.lattice import build_count, build_lattice_auto
+from repro import obs
+from repro.core.lattice import build_lattice_auto
 from repro.gp import (GPParams, SimplexGP, SimplexGPConfig,
                       mll_value_and_grad, posterior)
 
@@ -64,18 +65,18 @@ def _measure(model, params, x, y, xs, *, step_cap, post_cap, key):
     """(builds/step, step_s, mll, builds/posterior, posterior_s)."""
     step = jax.jit(lambda p, k: mll_value_and_grad(model, p, x, y, k,
                                                    cap=step_cap))
-    c0 = build_count()
+    c0 = obs.counter("lattice.builds")
     res = jax.block_until_ready(step(params, key))  # trace + compile
-    builds_step = build_count() - c0
+    builds_step = obs.counter("lattice.builds") - c0
     step_s = timeit(step, params, key)
     mll = float(res.mll)
 
     post = jax.jit(lambda p, k: posterior(model, p, x, y, xs, key=k,
                                           variance_rank=VARIANCE_RANK,
                                           cap=post_cap))
-    c0 = build_count()
+    c0 = obs.counter("lattice.builds")
     jax.block_until_ready(post(params, key).mean)
-    builds_post = build_count() - c0
+    builds_post = obs.counter("lattice.builds") - c0
     post_s = timeit(post, params, key)
     return builds_step, step_s, mll, builds_post, post_s
 

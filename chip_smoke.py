@@ -37,6 +37,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
+from repro import obs  # noqa: E402
 from repro.core import kernels_math as km  # noqa: E402
 from repro.core import lattice as lat_mod  # noqa: E402
 from repro.core.exact import ExactGP  # noqa: E402
@@ -88,26 +89,20 @@ def tree_rel_err(a, b) -> float:
     return rel_err(flat(a), flat(b))
 
 
-class CompileClock:
-    """Seconds XLA spent compiling, summed from JAX's own compile events."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
+def compile_seconds(since: float) -> float:
+    """Seconds XLA spent compiling since ``since`` (``time.perf_counter``),
+    from JAX's own compile events (``repro.obs``'s JIT record)."""
+    return sum(e.t1 - e.t0 for e in obs.jit_events()
+               if e.kind == "compile" and e.t0 >= since)
 
 
-def run_phase(name: str, fn, clock: CompileClock,
-              failed: list[str] | None = None):
+def run_phase(name: str, fn, failed: list[str] | None = None):
     """Run one phase and print its wall, compile and run seconds.
 
     With ``failed``, a failed check is recorded there and the run goes
     on, so one run reports every comparison; without it, it propagates.
     """
-    c0, t0 = clock.seconds, time.perf_counter()
+    t0 = time.perf_counter()
     out = None
     try:
         out = fn()
@@ -118,7 +113,7 @@ def run_phase(name: str, fn, clock: CompileClock,
         failed.append(name)
     finally:
         wall = time.perf_counter() - t0
-        comp = clock.seconds - c0
+        comp = compile_seconds(t0)
         log(f"[{name}] wall_s={wall:.3f} compile_s={comp:.3f} "
             f"run_s={wall - comp:.3f}")
     return out
@@ -282,36 +277,32 @@ def phase_exact_parity(*, seed: int, d: int = 3, n: int = 192,
           f"posterior mean RMSE {rmse_s} vs exact {rmse_e}")
 
 
-def one_chip(clock: CompileClock, *, seed: int, epochs: int = EPOCHS,
+def one_chip(*, seed: int, epochs: int = EPOCHS,
              scale: float = 1.0) -> list[str]:
     """The one-chip path; returns the names of the phases that failed a
     check (data, fit and the lattice must pass for the rest to run)."""
     failed: list[str] = []
-    ds = run_phase("data", lambda: load(DATASET, scale=scale, seed=seed),
-                   clock)
+    ds = run_phase("data", lambda: load(DATASET, scale=scale, seed=seed))
     log(f"  {DATASET}: n_train={ds.n} d={ds.d} n_val={ds.x_val.shape[0]} "
         f"n_test={ds.x_test.shape[0]}")
     model = SimplexGP(SimplexGPConfig())
     rank = EngineConfig().variance_rank
     res = run_phase("fit", lambda: phase_fit(model, ds, epochs=epochs,
-                                             seed=seed), clock)
+                                             seed=seed))
     params = res.params
     x, y = jnp.asarray(ds.x_train), jnp.asarray(ds.y_train)
-    lat = run_phase("lattice", lambda: phase_lattice(model, params, x),
-                    clock)
+    lat = run_phase("lattice", lambda: phase_lattice(model, params, x))
     run_phase("mvm_parity", lambda: phase_mvm_parity(
-        model, lat, c=1 + model.config.num_probes, seed=seed), clock,
-        failed)
+        model, lat, c=1 + model.config.num_probes, seed=seed), failed)
     run_phase("freeze", lambda: phase_freeze(model, params, x, y,
                                              cap=lat.cap, rank=rank,
-                                             seed=seed), clock, failed)
+                                             seed=seed), failed)
     xt = np.asarray(ds.x_test)
     queries = [jnp.asarray(np.resize(xt, (b, ds.d))) for b in QUERY_BATCHES]
     run_phase("serve", lambda: phase_serve(model, params, x, y, queries,
                                            cap=lat.cap, rank=rank,
-                                           seed=seed + 1), clock, failed)
-    run_phase("exact_parity", lambda: phase_exact_parity(seed=seed), clock,
-              failed)
+                                           seed=seed + 1), failed)
+    run_phase("exact_parity", lambda: phase_exact_parity(seed=seed), failed)
     return failed
 
 
@@ -422,30 +413,29 @@ def phase_mesh_serve(model: SimplexGP, params: GPParams, x, y, queries,
     check(sum(counts.values()) == 0, f"serving collectives: {counts}")
 
 
-def four_chips(clock: CompileClock, *, seed: int, epochs: int = EPOCHS,
+def four_chips(*, seed: int, epochs: int = EPOCHS,
                scale: float = 1.0) -> list[str]:
     """The 4-chip path; returns the names of the phases that failed a
     check (data and the mesh fit must pass for the rest to run)."""
     failed: list[str] = []
     mesh = sx.data_mesh(4)
-    ds = run_phase("data", lambda: load(DATASET, scale=scale, seed=seed),
-                   clock)
+    ds = run_phase("data", lambda: load(DATASET, scale=scale, seed=seed))
     log(f"  {DATASET}: n_train={ds.n} d={ds.d} mesh={dict(mesh.shape)}")
     model = SimplexGP(SimplexGPConfig())
     res = run_phase("mesh_fit", lambda: phase_mesh_fit(
-        model, ds, mesh, epochs=epochs, seed=seed), clock)
+        model, ds, mesh, epochs=epochs, seed=seed))
     run_phase("mesh_step", lambda: phase_mesh_step(
         model, ds, mesh, cap=res.history[0]["cap"], steps=epochs,
-        seed=seed), clock, failed)
+        seed=seed), failed)
     x, y = jnp.asarray(ds.x_train), jnp.asarray(ds.y_train)
     run_phase("mesh_mvm", lambda: phase_mesh_mvm(
         model, res.params, x, mesh, c=1 + model.config.num_probes,
-        seed=seed), clock, failed)
+        seed=seed), failed)
     xt = np.asarray(ds.x_test)
     queries = [jnp.asarray(np.resize(xt, (b, ds.d))) for b in (1000, 4096)]
     run_phase("mesh_serve", lambda: phase_mesh_serve(
         model, res.params, x, y, queries, mesh,
-        rank=EngineConfig().variance_rank, seed=seed), clock, failed)
+        rank=EngineConfig().variance_rank, seed=seed), failed)
     for dev in mesh.devices.flat:
         stats = dev.memory_stats() or {}
         log(f"  device {dev.id} bytes_in_use={stats.get('bytes_in_use')} "
@@ -475,11 +465,10 @@ def main(argv=None) -> int:
     log(f"device platform={dev.platform} kind={dev.device_kind} "
         f"count={len(devices)} jax={jax.__version__} "
         f"compile_cache={enable_compile_cache()}")
-    clock = CompileClock()
     t0 = time.perf_counter()
     try:
         run = one_chip if args.chips == 1 else four_chips
-        failed = run(clock, seed=args.seed)
+        failed = run(seed=args.seed)
     except Exception:  # noqa: BLE001 — any failed phase fails the run
         traceback.print_exc()
         print("chip_smoke: FAILED", file=sys.stderr)
@@ -489,7 +478,7 @@ def main(argv=None) -> int:
         return 1
     stats = dev.memory_stats() or {}
     log(f"total wall_s={time.perf_counter() - t0:.3f} "
-        f"compile_s={clock.seconds:.3f} "
+        f"compile_s={compile_seconds(t0):.3f} "
         f"peak_bytes_in_use={stats.get('peak_bytes_in_use')}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

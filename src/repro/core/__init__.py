@@ -14,14 +14,13 @@ from repro.core import kernels_math
 from repro.core.filtering import (FilterSpec, LatticeCache, filter_mvm,
                                   lattice_filter, lattice_filter_with,
                                   mvm_operator, spec_for)
-from repro.core.lattice import (Lattice, build_count, build_lattice,
-                                build_lattice_auto, default_capacity,
-                                suggest_capacity)
+from repro.core.lattice import (Lattice, build_lattice, build_lattice_auto,
+                                default_capacity, suggest_capacity)
 from repro.core.stencil import Stencil, make_stencil
 
 __all__ = [
     "kernels_math", "FilterSpec", "LatticeCache", "filter_mvm",
     "lattice_filter", "lattice_filter_with", "mvm_operator", "spec_for",
-    "Lattice", "build_count", "build_lattice", "build_lattice_auto",
+    "Lattice", "build_lattice", "build_lattice_auto",
     "default_capacity", "suggest_capacity", "Stencil", "make_stencil",
 ]

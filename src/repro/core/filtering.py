@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import lattice as lat_mod
 from repro.core.lattice import Lattice
 from repro.core.stencil import Stencil
@@ -147,6 +148,7 @@ def _filter_bwd(spec, res, g):
     return _filter_bwd_core(spec, lat, z, v, weights, dweights, g)
 
 
+@obs.scope("grad")
 def _filter_bwd_core(spec, lat, z, v, weights, dweights, g):
     """Shared §4.2 backward pass for both filter entry points."""
     n, d = z.shape
@@ -362,7 +364,7 @@ def _embed_queries(zq: Array, spacing: float, dtype):
     """Embed + pack + pack-overflow mask — the shared front half of every
     frozen-slice entry point. Returns (q_packed, weights, rank, active);
     ONE ``simplex_embed`` per call, which is what the multi-output predict
-    path's one-embed-per-batch pin (``lattice.embed_count``) counts."""
+    path's one-embed-per-batch pin (the ``lattice.embeds`` counter) counts."""
     b, d = zq.shape
     keys, w, rank = lat_mod.simplex_embed_ranked(zq, spacing)
     q_packed = jnp.stack(lat_mod._pack_key_cols(keys), axis=1)

@@ -28,8 +28,8 @@ when the lengthscale/spacing moves enough to change the rounding of inputs
 to simplex vertices — which in practice means once per hyperparameter
 setting. Training/prediction share ONE build per step through
 ``filtering.lattice_filter_with`` / ``filtering.LatticeCache`` (DESIGN.md
-§9); ``build_count()`` below exposes a call counter so benchmarks and smoke
-tests can assert the builds-per-step contract.
+§9); every build bumps the ``lattice.builds`` counter of ``repro.obs`` so
+benchmarks and smoke tests can assert the builds-per-step contract.
 
 Geometry facts used below (verified in tests/test_lattice.py):
   * the elevation basis E (paper Eq. 7 neighborhood) has orthogonal columns
@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels.hash import ops as hash_ops
 
 Array = jax.Array
@@ -138,20 +139,6 @@ def _rank_scatter(rank: Array, vals: Array, affine: bool = False) -> Array:
     return out[:, : d + 1]
 
 
-# --- embed instrumentation ---------------------------------------------------
-# ``simplex_embed`` increments this on every Python-level call (trace-level
-# under jit) — the serving analogue of ``build_count()``. The multi-output
-# serving path (gp/serve.predict_multi) is pinned to ONE embed per query
-# batch regardless of the number of output channels (DESIGN.md §15).
-
-_EMBED_STATS = {"embeds": 0}
-
-
-def embed_count() -> int:
-    """Total ``simplex_embed`` invocations (trace-level under jit)."""
-    return _EMBED_STATS["embeds"]
-
-
 def simplex_embed_ranked(z: Array, spacing: float):
     """``simplex_embed`` that also returns the coordinate ranks.
 
@@ -165,7 +152,10 @@ def simplex_embed_ranked(z: Array, spacing: float):
       weights: (n, d+1) float32 — barycentric weights (sum to 1).
       rank:    (n, d+1) int32 — fixed-up descending rank per coordinate.
     """
-    _EMBED_STATS["embeds"] += 1
+    # the ``lattice.embeds`` counter: the multi-output serving path
+    # (gp/serve.predict_multi) is pinned to ONE embed per query batch
+    # whatever the number of output channels (DESIGN.md §15)
+    obs.count("lattice.embeds")
     n, d = z.shape
     el = elevate(z, spacing)  # (n, d+1)
 
@@ -284,20 +274,6 @@ class Lattice:
 def _lex_sort(cols: Sequence[Array], payloads: Sequence[Array]):
     out = jax.lax.sort(tuple(cols) + tuple(payloads), num_keys=len(cols))
     return out[: len(cols)], out[len(cols):]
-
-
-# --- build instrumentation --------------------------------------------------
-# ``build_lattice`` increments this on every Python-level call. Inside a jit
-# trace that is once per *traced* build, i.e. exactly the number of lattice
-# constructions baked into the compiled program — what the shared-lattice
-# pipeline (DESIGN.md §9) drives to 1 per training step / posterior.
-
-_BUILD_STATS = {"builds": 0}
-
-
-def build_count() -> int:
-    """Total ``build_lattice`` invocations (trace-level under jit)."""
-    return _BUILD_STATS["builds"]
 
 
 # --- packed sort keys (§Perf iteration C1/C2) -------------------------------
@@ -497,19 +473,22 @@ def build_lattice_auto(z: Array, *, spacing: float, r: int = 1,
     """
     n, d = z.shape
     worst = default_capacity(n, d)
-    if cap is None:
-        cap = suggest_capacity(n, d, spacing, r=r, z=z)
-    for _ in range(max_tries):
-        lat = build_lattice(z, spacing=spacing, r=r, cap=min(cap, worst),
-                            backend=backend)
-        if bool(lat.pack_overflow):
-            # coordinate range, not capacity: growth cannot help — return
-            # with the overflow flag set so the caller sees invalid results
-            return lat
-        if not bool(lat.overflow) or cap >= worst:
-            return lat
-        cap *= growth
-    return lat  # pragma: no cover - max_tries exhausts only past worst case
+    with obs.span("build_auto") as attrs:
+        if cap is None:
+            cap = suggest_capacity(n, d, spacing, r=r, z=z)
+        for tries in range(1, max_tries + 1):
+            attrs["tries"] = tries
+            lat = build_lattice(z, spacing=spacing, r=r, cap=min(cap, worst),
+                                backend=backend)
+            if bool(lat.pack_overflow):
+                # coordinate range, not capacity: growth cannot help —
+                # return with the overflow flag set so the caller sees
+                # invalid results
+                return lat
+            if not bool(lat.overflow) or cap >= worst:
+                return lat
+            cap *= growth
+        return lat  # pragma: no cover - exhausts only past worst case
 
 
 def build_lattice(z: Array, *, spacing: float, r: int = 1,
@@ -534,7 +513,11 @@ def build_lattice(z: Array, *, spacing: float, r: int = 1,
     n, d = z.shape
     if cap is None:
         cap = default_capacity(n, d)
-    _BUILD_STATS["builds"] += 1
+    # the ``lattice.builds`` counter: once per traced build under jit, i.e.
+    # the constructions baked into the compiled program — what the
+    # shared-lattice pipeline (DESIGN.md §9) drives to 1 per training step
+    # and per posterior
+    obs.count("lattice.builds")
     resolved = hash_ops.resolve_build_backend(backend)
     if resolved == "sort":
         return _build_lattice_impl(z, spacing=spacing, r=r, cap=cap)
@@ -546,46 +529,55 @@ def build_lattice(z: Array, *, spacing: float, r: int = 1,
 def _build_lattice_impl(z: Array, *, spacing: float, r: int,
                         cap: int) -> Lattice:
     n, d = z.shape
-    keys, weights = simplex_embed(z, spacing)  # (n, d+1, d+1), (n, d+1)
     big = n * (d + 1)
+    with obs.scope("build"):
+        with obs.scope("build.embed"):
+            keys, weights = simplex_embed(z, spacing)  # (n,d+1,d+1), (n,d+1)
+            cols = _pack_key_cols(keys)
 
-    # ---- exact dedup via lexicographic sort over PACKED keys ---------------
-    # Packing is lossless (C2), so the only payload is the permutation; the
-    # sorted coordinates come back out of the packed key columns themselves.
-    cols = _pack_key_cols(keys)
-    payload = jnp.arange(big, dtype=jnp.int32)
-    sorted_cols, (perm,) = _lex_sort(cols, [payload])
-    spacked = jnp.stack(sorted_cols, axis=1)
-    skeys = _unpack_key_cols(spacked, d + 1)  # (big, d+1) sorted coords
-    new_group = jnp.concatenate([
-        jnp.ones((1,), bool),
-        jnp.any(spacked[1:] != spacked[:-1], axis=1),
-    ])
-    uid_sorted = jnp.cumsum(new_group.astype(jnp.int32)) - 1  # (big,)
-    m = uid_sorted[-1] + 1
-    pack_ovf = _pack_overflow(keys)
-    overflow = (m > cap) | pack_ovf
-    slot_sorted = jnp.minimum(uid_sorted, cap)  # overflowed uniques -> dump row
+        # ---- exact dedup via lexicographic sort over PACKED keys -----------
+        # Packing is lossless (C2), so the only payload is the permutation;
+        # the sorted coordinates come back out of the packed key columns.
+        with obs.scope("build.dedup"):
+            payload = jnp.arange(big, dtype=jnp.int32)
+            sorted_cols, (perm,) = _lex_sort(cols, [payload])
+            spacked = jnp.stack(sorted_cols, axis=1)
+            skeys = _unpack_key_cols(spacked, d + 1)  # (big, d+1) sorted
+            new_group = jnp.concatenate([
+                jnp.ones((1,), bool),
+                jnp.any(spacked[1:] != spacked[:-1], axis=1),
+            ])
+            uid_sorted = jnp.cumsum(new_group.astype(jnp.int32)) - 1
+            m = uid_sorted[-1] + 1
+            pack_ovf = _pack_overflow(keys)
+            overflow = (m > cap) | pack_ovf
+            # overflowed uniques -> dump row
+            slot_sorted = jnp.minimum(uid_sorted, cap)
 
-    # lattice point coords (every member of a group writes the same value)
-    coords = jnp.zeros((cap + 1, d + 1), jnp.int32).at[slot_sorted].set(skeys)
-    valid = jnp.zeros((cap + 1,), bool).at[slot_sorted].set(True)
-    valid = valid.at[cap].set(False)
+            # lattice point coords (every member of a group writes the same)
+            coords = jnp.zeros((cap + 1, d + 1), jnp.int32).at[
+                slot_sorted].set(skeys)
+            valid = jnp.zeros((cap + 1,), bool).at[slot_sorted].set(True)
+            valid = valid.at[cap].set(False)
 
-    # per-(input, vertex) slot ids, back in original order
-    seg_ids = jnp.zeros((big,), jnp.int32).at[perm].set(slot_sorted)
+            # per-(input, vertex) slot ids, back in original order
+            seg_ids = jnp.zeros((big,), jnp.int32).at[perm].set(slot_sorted)
 
-    # ---- sorted splat plan (DESIGN.md §8) ----------------------------------
-    # Contributions in sorted order: original flat index f = i*(d+1) + k, so
-    # the input row is f // (d+1); segment boundaries are the dedup groups;
-    # the last member per slot indexes the segmented prefix scan's result.
-    sort_row = perm // (d + 1)
-    sort_w = weights.reshape(big)[perm]
-    idx = jnp.arange(big, dtype=jnp.int32)
-    row_last = jnp.zeros((cap + 1,), jnp.int32).at[slot_sorted].max(idx)
+        # ---- sorted splat plan (DESIGN.md §8) ------------------------------
+        # Contributions in sorted order: original flat index f = i*(d+1) + k,
+        # so the input row is f // (d+1); segment boundaries are the dedup
+        # groups; the last member per slot indexes the segmented prefix
+        # scan's result.
+        with obs.scope("build.splat_plan"):
+            sort_row = perm // (d + 1)
+            sort_w = weights.reshape(big)[perm]
+            idx = jnp.arange(big, dtype=jnp.int32)
+            row_last = jnp.zeros((cap + 1,), jnp.int32).at[slot_sorted].max(
+                idx)
 
-    # ---- blur neighbor table via merge-sort lookup -------------------------
-    nbr = _neighbor_table(coords, valid, d=d, r=r, cap=cap)
+        # ---- blur neighbor table via merge-sort lookup ---------------------
+        with obs.scope("build.neighbours"):
+            nbr = _neighbor_table(coords, valid, d=d, r=r, cap=cap)
 
     return Lattice(coords=coords, valid=valid, m=m, seg_ids=seg_ids,
                    weights=weights, nbr=nbr, overflow=overflow,
@@ -771,52 +763,61 @@ def _build_lattice_hash_impl(z: Array, *, spacing: float, r: int, cap: int,
     placement vs lex order) differs.
     """
     n, d = z.shape
-    keys, weights = simplex_embed(z, spacing)  # (n, d+1, d+1), (n, d+1)
     big = n * (d + 1)
-    packed = jnp.stack(_pack_key_cols(keys), axis=1)  # (big, npk)
     hcap = hash_ops.hash_capacity(cap)
+    with obs.scope("build"):
+        with obs.scope("build.embed"):
+            keys, weights = simplex_embed(z, spacing)  # (n,d+1,d+1), (n,d+1)
+            packed = jnp.stack(_pack_key_cols(keys), axis=1)  # (big, npk)
 
-    # ---- dedup via hash insert --------------------------------------------
-    owner, slot_row, row_ok = hash_ops.hash_insert(packed, hcap,
-                                                   backend=backend)
-    occ = owner < big  # occupied hash slots (owner row id < N, EMPTY == N)
-    m = jnp.sum(occ.astype(jnp.int32))
-    dense = jnp.cumsum(occ.astype(jnp.int32)) - 1  # hash slot -> dense id
-    dense_of = jnp.where(occ, jnp.minimum(dense, cap), cap)
-    tkeys = hash_ops.table_keys(owner, packed)  # (hcap, npk), empty -> SENT
-    pack_ovf = _pack_overflow(keys)
-    overflow = (m > cap) | ~jnp.all(row_ok) | pack_ovf
+        # ---- dedup via hash insert ----------------------------------------
+        with obs.scope("build.dedup"):
+            owner, slot_row, row_ok = hash_ops.hash_insert(packed, hcap,
+                                                           backend=backend)
+            occ = owner < big  # occupied hash slots (owner id < N, EMPTY N)
+            m = jnp.sum(occ.astype(jnp.int32))
+            dense = jnp.cumsum(occ.astype(jnp.int32)) - 1  # slot -> dense id
+            dense_of = jnp.where(occ, jnp.minimum(dense, cap), cap)
+            tkeys = hash_ops.table_keys(owner, packed)  # empty -> SENT
+            pack_ovf = _pack_overflow(keys)
+            overflow = (m > cap) | ~jnp.all(row_ok) | pack_ovf
 
-    # per-(input, vertex) slot ids, already in original order (no perm)
-    seg_ids = jnp.where(row_ok, dense_of[slot_row], cap)
+            # per-(input, vertex) slot ids, already in original order
+            seg_ids = jnp.where(row_ok, dense_of[slot_row], cap)
 
-    # dense lattice-point table (scatter over hcap rows only — cheap)
-    dense_clip = jnp.where(occ & (dense < cap), dense, cap)
-    coords = jnp.zeros((cap + 1, d + 1), jnp.int32).at[dense_clip].set(
-        jnp.where(occ[:, None], _unpack_key_cols(tkeys, d + 1), 0))
-    valid = jnp.zeros((cap + 1,), bool).at[dense_clip].set(occ)
-    valid = valid.at[cap].set(False)
+            # dense lattice-point table (scatter over hcap rows only)
+            dense_clip = jnp.where(occ & (dense < cap), dense, cap)
+            coords = jnp.zeros((cap + 1, d + 1), jnp.int32).at[
+                dense_clip].set(
+                jnp.where(occ[:, None], _unpack_key_cols(tkeys, d + 1), 0))
+            valid = jnp.zeros((cap + 1,), bool).at[dense_clip].set(occ)
+            valid = valid.at[cap].set(False)
 
-    # ---- sorted splat plan (DESIGN.md §8) ----------------------------------
-    seg_sorted, perm = _splat_plan_counting(seg_ids, big=big, cap=cap)
-    sort_row = perm // (d + 1)
-    sort_w = weights.reshape(big)[perm]
-    seg_head = jnp.concatenate([jnp.ones((1,), bool),
-                                seg_sorted[1:] != seg_sorted[:-1]])
-    # last sorted index per slot via binary search (no scatter): seg_sorted
-    # is sorted, so right-boundary - 1 is each slot's last member
-    row_last = jnp.clip(
-        jnp.searchsorted(seg_sorted, jnp.arange(cap + 1, dtype=jnp.int32),
-                         side="right").astype(jnp.int32) - 1, 0, big - 1)
+        # ---- sorted splat plan (DESIGN.md §8) ------------------------------
+        with obs.scope("build.splat_plan"):
+            seg_sorted, perm = _splat_plan_counting(seg_ids, big=big, cap=cap)
+            sort_row = perm // (d + 1)
+            sort_w = weights.reshape(big)[perm]
+            seg_head = jnp.concatenate([jnp.ones((1,), bool),
+                                        seg_sorted[1:] != seg_sorted[:-1]])
+            # last sorted index per slot via binary search (no scatter):
+            # seg_sorted is sorted, so right-boundary - 1 is each slot's last
+            row_last = jnp.clip(
+                jnp.searchsorted(seg_sorted,
+                                 jnp.arange(cap + 1, dtype=jnp.int32),
+                                 side="right").astype(jnp.int32) - 1,
+                0, big - 1)
 
-    # ---- blur neighbor table via hash lookup -------------------------------
-    q_packed, src_valid = _neighbor_queries(coords, valid, d=d, r=r, cap=cap)
-    hres = hash_ops.hash_lookup(tkeys, q_packed, src_valid, hcap,
-                                backend=backend)
-    nbr = _neighbor_layout(
-        jnp.where(src_valid & (hres >= 0),
-                  dense_of[jnp.clip(hres, 0, hcap - 1)], cap),
-        d=d, r=r, cap=cap)
+        # ---- blur neighbor table via hash lookup ---------------------------
+        with obs.scope("build.neighbours"):
+            q_packed, src_valid = _neighbor_queries(coords, valid, d=d, r=r,
+                                                    cap=cap)
+            hres = hash_ops.hash_lookup(tkeys, q_packed, src_valid, hcap,
+                                        backend=backend)
+            nbr = _neighbor_layout(
+                jnp.where(src_valid & (hres >= 0),
+                          dense_of[jnp.clip(hres, 0, hcap - 1)], cap),
+                d=d, r=r, cap=cap)
 
     return Lattice(coords=coords, valid=valid, m=m, seg_ids=seg_ids,
                    weights=weights, nbr=nbr, overflow=overflow,

@@ -36,6 +36,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.filtering import LatticeCache
 from repro.gp.models import GPParams, SimplexGP
 from repro.solvers.cg import cg as cg_solve
@@ -152,7 +153,8 @@ def mll_value_and_grad(model: SimplexGP, params: GPParams, x: Array,
     def neg_surrogate(p: GPParams) -> Array:
         return -model.quad_form(p, x, a_blk, b_blk, lat=shared)
 
-    grads = jax.grad(neg_surrogate)(params)
+    with obs.scope("grad"):
+        grads = jax.grad(neg_surrogate)(params)
     return MLLResult(mll=mll, grads=grads, cg_iters=info.iterations,
                      cg_residual=info.residual_norms[0],
                      overflow=op.lattice.overflow,

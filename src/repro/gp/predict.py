@@ -29,6 +29,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import filtering
 from repro.core.filtering import LatticeCache
 from repro.core.lattice import Lattice, build_lattice
@@ -90,9 +91,30 @@ def cross_mvm(model: SimplexGP, params: GPParams, x: Array, xs: Array,
     if lat is None:
         lat = _joint_lattice(model, params, x, xs, cap=None, cache=cache,
                              mesh=mesh)
-    vj = jnp.concatenate([v, jnp.zeros((ns, v.shape[1]), v.dtype)], axis=0)
-    out = _joint_filter(model, lat, vj, x.dtype, mesh=mesh)
-    return os_ * out[n:]
+    with obs.scope("posterior.cross"):
+        vj = jnp.concatenate([v, jnp.zeros((ns, v.shape[1]), v.dtype)],
+                             axis=0)
+        out = _joint_filter(model, lat, vj, x.dtype, mesh=mesh)
+        return os_ * out[n:]
+
+
+def _love_tridiag(lres) -> Array:
+    """The dense (k, k) Lanczos tridiagonal T of LOVE's first column."""
+    valid = lres.valid[:, 0]
+    off = lres.betas[:-1, 0] * valid[:-1] * valid[1:]
+    return (jnp.diag(jnp.where(valid, lres.alphas[:, 0], 1.0))
+            + jnp.diag(off, 1) + jnp.diag(off, -1))
+
+
+@obs.scope("posterior.var")
+def _love_var(tdense: Array, ksq: Array, prior_var: Array) -> Array:
+    """var_* = k(0) - (K_*X Q) T^{-1} (K_*X Q)^T, clipped to [1e-6, k(0)]
+    (k(0) = outputscale for unit profiles); ``ksq`` is (n*, k)."""
+    sol = jnp.linalg.solve(
+        tdense + 1e-6 * jnp.eye(tdense.shape[0], dtype=tdense.dtype),
+        ksq.T)  # (k, n*)
+    var = prior_var - jnp.sum(ksq * sol.T, axis=1)
+    return jnp.clip(var, 1e-6, prior_var)
 
 
 def posterior(model: SimplexGP, params: GPParams, x: Array, y: Array,
@@ -132,23 +154,14 @@ def posterior(model: SimplexGP, params: GPParams, x: Array, y: Array,
     q0 = y[:, None] + 1e-3 * jax.random.normal(key, (n, 1), x.dtype)
     lres = lanczos_run(mvm, q0, variance_rank)
     q = lres.q[:, :, 0].T  # (n, k)
-    tdense = (jnp.diag(jnp.where(lres.valid[:, 0], lres.alphas[:, 0], 1.0))
-              + jnp.diag(lres.betas[:-1, 0] * lres.valid[:-1, 0]
-                         * lres.valid[1:, 0], 1)
-              + jnp.diag(lres.betas[:-1, 0] * lres.valid[:-1, 0]
-                         * lres.valid[1:, 0], -1))
+    tdense = _love_tridiag(lres)
 
     # ONE batched cross filtering for [u | Q]: (1 + k) channels at once.
     ksall = cross_mvm(model, params, x, xs, jnp.concatenate([u, q], axis=1),
                       lat=lat, mesh=mesh)
-    mean = ksall[:, 0]
-    ksq = ksall[:, 1:]  # (n*, k)
-    sol = jnp.linalg.solve(tdense + 1e-6 * jnp.eye(tdense.shape[0], dtype=x.dtype),
-                           ksq.T)  # (k, n*)
-    prior_var = os_  # k(0) = outputscale for unit profiles
-    var = prior_var - jnp.sum(ksq * sol.T, axis=1)
-    return Posterior(mean=mean, var=jnp.clip(var, 1e-6, prior_var),
-                     overflow=lat.overflow, pack_overflow=lat.pack_overflow)
+    var = _love_var(tdense, ksall[:, 1:], os_)
+    return Posterior(mean=ksall[:, 0], var=var, overflow=lat.overflow,
+                     pack_overflow=lat.pack_overflow)
 
 
 def _posterior_rebuild(model: SimplexGP, params: GPParams, x: Array,
@@ -166,18 +179,9 @@ def _posterior_rebuild(model: SimplexGP, params: GPParams, x: Array,
 
     q0 = y[:, None] + 1e-3 * jax.random.normal(key, (x.shape[0], 1), x.dtype)
     lres = lanczos_run(op.mvm, q0, variance_rank)
-    q = lres.q[:, :, 0].T  # (n, k)
-    tdense = (jnp.diag(jnp.where(lres.valid[:, 0], lres.alphas[:, 0], 1.0))
-              + jnp.diag(lres.betas[:-1, 0] * lres.valid[:-1, 0]
-                         * lres.valid[1:, 0], 1)
-              + jnp.diag(lres.betas[:-1, 0] * lres.valid[:-1, 0]
-                         * lres.valid[1:, 0], -1))
-    ksq = cross_mvm(model, params, x, xs, q)  # (n*, k)
-    sol = jnp.linalg.solve(tdense + 1e-6 * jnp.eye(tdense.shape[0], dtype=x.dtype),
-                           ksq.T)  # (k, n*)
-    prior_var = op.outputscale  # k(0) = outputscale for unit profiles
-    var = prior_var - jnp.sum(ksq * sol.T, axis=1)
-    return Posterior(mean=mean, var=jnp.clip(var, 1e-6, prior_var),
+    ksq = cross_mvm(model, params, x, xs, lres.q[:, :, 0].T)  # (n*, k)
+    return Posterior(mean=mean,
+                     var=_love_var(_love_tridiag(lres), ksq, op.outputscale),
                      overflow=op.lattice.overflow,
                      pack_overflow=op.lattice.pack_overflow)
 

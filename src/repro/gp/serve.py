@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import filtering
 from repro.core import lattice as lat_mod
 from repro.core.filtering import LatticeCache
@@ -480,6 +481,7 @@ def validate_predictor(pred: Predictor, *,
     return ValidationReport(ok=not fails, failures=tuple(fails))
 
 
+@obs.scope("serve.slice")
 def _predict_core(pred: Predictor, xs: Array, *, backend: str,
                   interpret: bool | None = None):
     zq = xs / pred.lengthscale[None, :]
@@ -564,13 +566,14 @@ def predict(pred: Predictor, xs: Array, *, backend: str | None = None,
 # -- Multi-output serving (DESIGN.md §15) ------------------------------------
 
 
+@obs.scope("serve.slice")
 def _predict_multi_core(mp: MultiPredictor, xs: Array, *, backend: str,
                         interpret: bool | None = None):
     """One embed + probe + batched contraction for ALL k channels.
 
     The hoisted multi-channel path: the embed/rank scratch is computed
     once per query batch inside the single ``slice_only`` call, not once
-    per output (pinned by the ``lattice.embed_count`` test) — the k
+    per output (pinned by the ``lattice.embeds`` counter test) — the k
     channels differ only in which table columns the one gathered row set
     contracts against.
     """
@@ -618,6 +621,7 @@ def predict_multi(mp: MultiPredictor, xs: Array, *,
 # -- Analytic query-space gradients (DESIGN.md §15) --------------------------
 
 
+@obs.scope("serve.slice")
 def _grad_blocks(index: LatticeIndex, tables: Array, xs: Array, ls: Array,
                  os_: Array, spacing: float, k_out: int):
     """Shared analytic d(mean, var)/dx* core for 1 and k output channels.

@@ -41,6 +41,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.lattice import build_lattice_auto, default_capacity
 from repro.gp import mll as mll_mod
 from repro.gp import predict as predict_mod
@@ -303,176 +304,190 @@ def fit(model: SimplexGP, x: Array, y: Array, *, x_val: Array, y_val: Array,
 
     def _rollback(epoch: int, reason: str):
         nonlocal opt, step, good, good_meta
-        if len(st.rollbacks) >= max_rollbacks:
-            raise RuntimeError(
-                f"fit: divergence guard exhausted after {max_rollbacks} "
-                f"rollback(s); last reason: {reason}")
-        restored = jax.tree.map(jnp.asarray, good)
-        st.params = restored["params"]
-        st.opt_state = restored["opt_state"]
-        st.best_params = restored["best_params"]
-        st.key = restored["key"]
-        st.epoch = int(good_meta["epoch"])
-        st.best_val_rmse = float(good_meta["best_val_rmse"])
-        st.stall = int(good_meta["stall"])
-        st.window = []  # post-restore losses rejoin a fresh band
-        st.lr_scale *= lr_backoff
-        st.jitter_raw = jitter_raw0 * (2 ** len(st.rollbacks))
-        # escalated jitter: a larger noise floor conditions K_hat better;
-        # raw-space additive keeps the bump monotone under softplus
-        st.params = dataclasses.replace(
-            st.params, raw_noise=st.params.raw_noise + st.jitter_raw)
-        entry = dict(epoch=epoch, reason=reason,
-                     restored_epoch=st.epoch, lr_scale=st.lr_scale,
-                     jitter_raw=st.jitter_raw)
-        st.rollbacks.append(entry)
-        report.rollbacks.append(entry)
-        opt = make_opt(st.lr_scale)
-        step = make_step(st.cap, opt)
-        if log_fn:
-            log_fn(f"rollback #{len(st.rollbacks)} at epoch {epoch} "
-                   f"({reason}): restored epoch {st.epoch}, "
-                   f"lr x{st.lr_scale:g}, jitter +{st.jitter_raw:g}")
+        with obs.span("rollback", epoch=epoch, reason=reason):
+            if len(st.rollbacks) >= max_rollbacks:
+                raise RuntimeError(
+                    f"fit: divergence guard exhausted after {max_rollbacks} "
+                    f"rollback(s); last reason: {reason}")
+            restored = jax.tree.map(jnp.asarray, good)
+            st.params = restored["params"]
+            st.opt_state = restored["opt_state"]
+            st.best_params = restored["best_params"]
+            st.key = restored["key"]
+            st.epoch = int(good_meta["epoch"])
+            st.best_val_rmse = float(good_meta["best_val_rmse"])
+            st.stall = int(good_meta["stall"])
+            st.window = []  # post-restore losses rejoin a fresh band
+            st.lr_scale *= lr_backoff
+            st.jitter_raw = jitter_raw0 * (2 ** len(st.rollbacks))
+            # escalated jitter: a larger noise floor conditions K_hat better;
+            # raw-space additive keeps the bump monotone under softplus
+            st.params = dataclasses.replace(
+                st.params, raw_noise=st.params.raw_noise + st.jitter_raw)
+            entry = dict(epoch=epoch, reason=reason,
+                         restored_epoch=st.epoch, lr_scale=st.lr_scale,
+                         jitter_raw=st.jitter_raw)
+            st.rollbacks.append(entry)
+            report.rollbacks.append(entry)
+            opt = make_opt(st.lr_scale)
+            step = make_step(st.cap, opt)
+            if log_fn:
+                log_fn(f"rollback #{len(st.rollbacks)} at epoch {epoch} "
+                       f"({reason}): restored epoch {st.epoch}, "
+                       f"lr x{st.lr_scale:g}, jitter +{st.jitter_raw:g}")
+
 
     def _checkpoint(metric: float | None):
         if manager is None:
             return
-        manager.save(st.epoch, st.arrays(), metric=metric,
-                     extra=st.extra())
+        with obs.span("checkpoint", epoch=st.epoch):
+            manager.save(st.epoch, st.arrays(), metric=metric,
+                         extra=st.extra())
         report.checkpoints_written += 1
 
     report.rollbacks.extend(st.rollbacks)
     history = []
     epoch = st.epoch + 1
     while epoch < epochs:
-        if faults is not None:
-            faults.kill_if_armed("fit")  # scripted device loss (os._exit)
-            faults.maybe_raise("fit")  # scripted crash (recovery tests)
-            if faults.take("fit", "nan_params") is not None:
-                st.params = dataclasses.replace(
-                    st.params, raw_lengthscale=st.params.raw_lengthscale
-                    .at[0].set(jnp.nan))
-            if faults.take("fit", "spike_params") is not None:
-                # near-zero noise: K_hat goes ill-conditioned and the
-                # data-fit term y^T K^-1 y explodes — a reliable, finite
-                # loss spike (unlike outputscale, whose logdet blow-up
-                # the truncated SLQ estimate underreports)
-                st.params = dataclasses.replace(
-                    st.params, raw_noise=st.params.raw_noise - 18.0)
-        st.key, k1, k2 = jax.random.split(st.key, 3)
-        t0 = time.perf_counter()
-        pre_breaches = 0 if watchdog is None else len(watchdog.breaches)
-        if watchdog is not None:
-            watchdog.start_step(epoch)
-        retries_left = step_retries
-        while True:
-            try:
-                if faults is not None:
-                    # consume the in-step schedule ONCE per dispatch (a
-                    # retry is a new dispatch) and hand the decision to
-                    # the compiled step as an operand; block so the
-                    # injected sleep/poison has materialized before the
-                    # flag is inspected, then raise the scripted fault
-                    # HERE on the host — the callback itself never raises
-                    code = faults.plan_step("fit_step")
-                    out = jax.block_until_ready(
-                        step(st.params, st.opt_state, k1, code))
-                    *out, probe = out
-                    if float(probe) != 0.0:
-                        raise faults_mod.InjectedFault(
-                            "injected exception at 'fit_step'")
-                else:
-                    out = step(st.params, st.opt_state, k1)
-                new_params, new_state, mll, iters, ovf, povf, gok = out
-            except Exception as err:  # noqa: BLE001 — non-injected re-raised
-                if (retries_left > 0 and faults is not None
-                        and faults_mod.is_injected(err)):
-                    # the step's outputs are discarded on the poison path
-                    # and nothing host-side was mutated — re-running it is
-                    # safe and (fault aside) replays the identical
-                    # computation
-                    retries_left -= 1
-                    entry = dict(epoch=epoch,
-                                 error=str(err).splitlines()[0][:200],
-                                 remaining=retries_left)
-                    report.retries.append(entry)
+        with obs.span("epoch", epoch=epoch):
+            if faults is not None:
+                faults.kill_if_armed("fit")  # scripted device loss (os._exit)
+                faults.maybe_raise("fit")  # scripted crash (recovery tests)
+                if faults.take("fit", "nan_params") is not None:
+                    st.params = dataclasses.replace(
+                        st.params, raw_lengthscale=st.params.raw_lengthscale
+                        .at[0].set(jnp.nan))
+                if faults.take("fit", "spike_params") is not None:
+                    # near-zero noise: K_hat goes ill-conditioned and the
+                    # data-fit term y^T K^-1 y explodes — a reliable, finite
+                    # loss spike (unlike outputscale, whose logdet blow-up
+                    # the truncated SLQ estimate underreports)
+                    st.params = dataclasses.replace(
+                        st.params, raw_noise=st.params.raw_noise - 18.0)
+            st.key, k1, k2 = jax.random.split(st.key, 3)
+            t0 = time.perf_counter()
+            pre_breaches = 0 if watchdog is None else len(watchdog.breaches)
+            if watchdog is not None:
+                watchdog.start_step(epoch)
+            retries_left = step_retries
+            while True:
+                try:
+                    with obs.span("step"):
+                        if faults is not None:
+                            # consume the in-step schedule ONCE per
+                            # dispatch (a retry is a new dispatch) and hand
+                            # the decision to the compiled step as an
+                            # operand; block so the injected sleep/poison
+                            # has materialized before the flag is
+                            # inspected, then raise the scripted fault HERE
+                            # on the host — the callback itself never raises
+                            code = faults.plan_step("fit_step")
+                            out = jax.block_until_ready(
+                                step(st.params, st.opt_state, k1, code))
+                            *out, probe = out
+                            if float(probe) != 0.0:
+                                raise faults_mod.InjectedFault(
+                                    "injected exception at 'fit_step'")
+                        else:
+                            out = jax.block_until_ready(
+                                step(st.params, st.opt_state, k1))
+                    new_params, new_state, mll, iters, ovf, povf, gok = out
+                except Exception as err:  # noqa: BLE001 — others re-raised
+                    if (retries_left > 0 and faults is not None
+                            and faults_mod.is_injected(err)):
+                        # the step's outputs are discarded on the poison
+                        # path and nothing host-side was mutated —
+                        # re-running it is safe and (fault aside) replays
+                        # the identical computation
+                        retries_left -= 1
+                        entry = dict(epoch=epoch,
+                                     error=str(err).splitlines()[0][:200],
+                                     remaining=retries_left)
+                        report.retries.append(entry)
+                        if log_fn:
+                            log_fn(f"transient step failure at epoch "
+                                   f"{epoch}: retrying ({retries_left} retr"
+                                   f"{'y' if retries_left == 1 else 'ies'} "
+                                   "left)")
+                        continue
+                    raise
+                with obs.span("step.read"):
+                    _check_pack(povf)
+                    overflowed = bool(ovf)
+                    mll, iters, gok = float(mll), int(iters), bool(gok)
+                if not overflowed or st.cap >= worst:
+                    break
+                st.cap = min(st.cap * CAP_GROWTH, worst)  # stale grads: regrow
+                with obs.span("regrow", cap=st.cap):
+                    step = make_step(st.cap, opt)
+            breached = False
+            if watchdog is not None:
+                step_seconds = time.perf_counter() - t0
+                watchdog.end_step(step_seconds)
+                breached = len(watchdog.breaches) > pre_breaches
+                if breached:
+                    report.watchdog_breaches.append(dict(
+                        epoch=epoch, deadline=watchdog.breaches[-1][1],
+                        seconds=step_seconds))
                     if log_fn:
-                        log_fn(f"transient step failure at epoch {epoch}: "
-                               f"retrying ({retries_left} retr"
-                               f"{'y' if retries_left == 1 else 'ies'} left)")
-                    continue
-                raise
-            _check_pack(povf)
-            if not bool(ovf) or st.cap >= worst:
+                        log_fn(f"watchdog breach at epoch {epoch}: step took "
+                               f"{step_seconds:.2f}s (deadline "
+                               f"{watchdog.breaches[-1][1]:.2f}s)")
+
+            # -- divergence guard (DESIGN.md §14) ---------------------------
+            loss = -mll if math.isfinite(mll) else float("nan")
+            if not (math.isfinite(mll) and gok):
+                _rollback(epoch, "non-finite loss/grads")
+                epoch = st.epoch + 1
+                continue
+            if _spike(loss):
+                _rollback(epoch, f"loss spike ({loss:.4g} outside the "
+                                 f"{len(st.window)}-epoch band)")
+                epoch = st.epoch + 1
+                continue
+
+            st.params, st.opt_state = new_params, new_state
+            dt = time.perf_counter() - t0
+            with obs.span("validation"):
+                while True:
+                    rmse_v, ovf, povf = val_rmse(st.params, k2)
+                    _check_pack(povf)
+                    if not bool(ovf) or st.cap_val >= worst_joint:
+                        break
+                    st.cap_val = min(st.cap_val * CAP_GROWTH, worst_joint)
+                    with obs.span("regrow", cap_val=st.cap_val):
+                        val_rmse = make_val(st.cap_val)
+                rmse = float(rmse_v)
+            st.window = (st.window + [loss])[-spike_window:]
+            history.append(dict(epoch=epoch, mll=mll, val_rmse=rmse,
+                                cg_iters=iters, seconds=dt, cap=st.cap))
+            if log_fn:
+                log_fn(f"epoch {epoch:3d}  mll/n {mll / x.shape[0]:+.4f}  "
+                       f"val_rmse {rmse:.4f}  cg_iters {iters}  {dt:.2f}s")
+            if rmse < st.best_val_rmse - 1e-5:
+                st.best_val_rmse = rmse
+                st.best_params = st.params
+                st.stall = 0
+            else:
+                st.stall += 1
+            st.epoch = epoch
+            report.completed_epochs += 1
+
+            # the just-completed epoch is the new rollback anchor (host copy,
+            # detached from the loop's live references)
+            good = jax.tree.map(jnp.asarray, st.arrays())
+            good_meta = st.extra()
+            if (epoch + 1) % max(ckpt_every, 1) == 0 or breached:
+                # a breach forces an immediate checkpoint: the slow epoch's
+                # result is valid (slow is not wrong), and if the mesh is
+                # about to shrink this is the state the resume picks up
+                _checkpoint(rmse)
+            if breached and watchdog_abort:
+                report.interrupted = "watchdog_breach"
                 break
-            st.cap = min(st.cap * CAP_GROWTH, worst)  # stale grads: regrow
-            step = make_step(st.cap, opt)
-        breached = False
-        if watchdog is not None:
-            step_seconds = time.perf_counter() - t0
-            watchdog.end_step(step_seconds)
-            breached = len(watchdog.breaches) > pre_breaches
-            if breached:
-                report.watchdog_breaches.append(dict(
-                    epoch=epoch, deadline=watchdog.breaches[-1][1],
-                    seconds=step_seconds))
-                if log_fn:
-                    log_fn(f"watchdog breach at epoch {epoch}: step took "
-                           f"{step_seconds:.2f}s (deadline "
-                           f"{watchdog.breaches[-1][1]:.2f}s)")
-
-        # -- divergence guard (DESIGN.md §14) -------------------------------
-        loss = float(-mll) if bool(jnp.isfinite(mll)) else float("nan")
-        if not (bool(jnp.isfinite(mll)) and bool(gok)):
-            _rollback(epoch, "non-finite loss/grads")
-            epoch = st.epoch + 1
-            continue
-        if _spike(loss):
-            _rollback(epoch, f"loss spike ({loss:.4g} outside the "
-                             f"{len(st.window)}-epoch band)")
-            epoch = st.epoch + 1
-            continue
-
-        st.params, st.opt_state = new_params, new_state
-        dt = time.perf_counter() - t0
-        while True:
-            rmse_v, ovf, povf = val_rmse(st.params, k2)
-            _check_pack(povf)
-            if not bool(ovf) or st.cap_val >= worst_joint:
+            if st.stall >= patience:
                 break
-            st.cap_val = min(st.cap_val * CAP_GROWTH, worst_joint)
-            val_rmse = make_val(st.cap_val)
-        rmse = float(rmse_v)
-        st.window = (st.window + [loss])[-spike_window:]
-        history.append(dict(epoch=epoch, mll=float(mll), val_rmse=rmse,
-                            cg_iters=int(iters), seconds=dt, cap=st.cap))
-        if log_fn:
-            log_fn(f"epoch {epoch:3d}  mll/n {float(mll)/x.shape[0]:+.4f}  "
-                   f"val_rmse {rmse:.4f}  cg_iters {int(iters)}  {dt:.2f}s")
-        if rmse < st.best_val_rmse - 1e-5:
-            st.best_val_rmse = rmse
-            st.best_params = st.params
-            st.stall = 0
-        else:
-            st.stall += 1
-        st.epoch = epoch
-        report.completed_epochs += 1
-
-        # the just-completed epoch is the new rollback anchor (host copy,
-        # detached from the loop's live references)
-        good = jax.tree.map(jnp.asarray, st.arrays())
-        good_meta = st.extra()
-        if (epoch + 1) % max(ckpt_every, 1) == 0 or breached:
-            # a breach forces an immediate checkpoint: the slow epoch's
-            # result is valid (slow is not wrong), and if the mesh is
-            # about to shrink this is the state the resume picks up
-            _checkpoint(rmse)
-        if breached and watchdog_abort:
-            report.interrupted = "watchdog_breach"
-            break
-        if st.stall >= patience:
-            break
-        epoch += 1
+            epoch += 1
 
     if manager is not None and history:
         _checkpoint(history[-1]["val_rmse"])  # final state always durable

@@ -31,6 +31,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import lattice as lat_mod
 from repro.core.lattice import Lattice
 from repro.kernels.blur.fused import fused_filter_pallas
@@ -209,47 +210,38 @@ def blur_pallas(lat: Lattice, vals: Array, stencil: tuple[float, ...], *,
 # ---------------------------------------------------------------------------
 
 
+def _blur_both(blur_fn, table: Array, *, symmetrize: bool,
+               transpose: bool) -> Array:
+    """``blur_fn(table, reverse)`` in the operator's order, averaged with
+    the opposite order when symmetrized."""
+    with obs.scope("mvm.blur"):
+        blurred = blur_fn(table, transpose)
+        if symmetrize:
+            blurred = 0.5 * (blurred + blur_fn(table, not transpose))
+    return blurred
+
+
 @functools.partial(jax.jit, static_argnames=("symmetrize", "transpose"))
 def _fused_xla(lat: Lattice, v: Array, weights: Array, *,
                symmetrize: bool, transpose: bool) -> Array:
-    table = lat_mod.splat_sorted(lat, v)
-    blurred = lat_mod.blur(lat, table, weights, reverse=transpose)
-    if symmetrize:
-        blurred_r = lat_mod.blur(lat, table, weights, reverse=not transpose)
-        blurred = 0.5 * (blurred + blurred_r)
-    return lat_mod.slice_(lat, blurred)
+    with obs.scope("mvm.splat"):
+        table = lat_mod.splat_sorted(lat, v)
+    blurred = _blur_both(
+        lambda t, rev: lat_mod.blur(lat, t, weights, reverse=rev), table,
+        symmetrize=symmetrize, transpose=transpose)
+    with obs.scope("mvm.slice"):
+        return lat_mod.slice_(lat, blurred)
 
 
 def _xla_reference(lat: Lattice, v: Array, weights: Array, *,
                    symmetrize: bool, transpose: bool) -> Array:
-    splatted = lat_mod.splat(lat, v)
-    blurred = lat_mod.blur(lat, splatted, weights, reverse=transpose)
-    if symmetrize:
-        blurred_r = lat_mod.blur(lat, splatted, weights,
-                                 reverse=not transpose)
-        blurred = 0.5 * (blurred + blurred_r)
-    return lat_mod.slice_(lat, blurred)
-
-
-# --- MVM instrumentation ----------------------------------------------------
-# ``lattice_mvm`` bumps these on every Python-level call (trace-level under
-# jit/scan — the number of lattice MVMs baked into the compiled program,
-# exactly like ``lattice.build_count``). ``cols`` accumulates the channel
-# width of each call, so a solver that batches k RHS into ONE (n, k) MVM per
-# iteration shows up as calls=1, cols=k — while a per-column loop would show
-# calls=k. tests/test_solvers.py pins the mBCG contract with this.
-
-_MVM_STATS = {"calls": 0, "cols": 0}
-
-
-def mvm_count() -> int:
-    """Total ``lattice_mvm`` invocations (trace-level under jit)."""
-    return _MVM_STATS["calls"]
-
-
-def mvm_cols() -> int:
-    """Total RHS columns across all ``lattice_mvm`` invocations."""
-    return _MVM_STATS["cols"]
+    with obs.scope("mvm.splat"):
+        splatted = lat_mod.splat(lat, v)
+    blurred = _blur_both(
+        lambda t, rev: lat_mod.blur(lat, t, weights, reverse=rev), splatted,
+        symmetrize=symmetrize, transpose=transpose)
+    with obs.scope("mvm.slice"):
+        return lat_mod.slice_(lat, blurred)
 
 
 def _concrete_taps(weights, taps):
@@ -284,8 +276,21 @@ def lattice_mvm(lat: Lattice, v: Array, weights: Array | None = None, *,
     if weights is None and taps is None:
         raise ValueError("lattice_mvm needs a stencil: pass weights= "
                          "(array) and/or taps= (concrete tuple)")
-    _MVM_STATS["calls"] += 1
-    _MVM_STATS["cols"] += int(v.shape[1])
+    # trace-level under jit/scan: the MVMs baked into the compiled program
+    # and their channel widths, so a solver that batches k RHS into ONE
+    # (n, k) MVM per iteration shows calls=1, cols=k where a per-column
+    # loop would show calls=k (tests/test_solvers.py pins mBCG with this)
+    obs.count("mvm.calls")
+    obs.count("mvm.cols", int(v.shape[1]))
+    with obs.scope("mvm"):
+        return _dispatch(lat, v, weights, taps=taps, symmetrize=symmetrize,
+                         transpose=transpose, backend=backend,
+                         block_p=block_p, interpret=interpret, mesh=mesh,
+                         axis_name=axis_name)
+
+
+def _dispatch(lat, v, weights, *, taps, symmetrize, transpose, backend,
+              block_p, interpret, mesh, axis_name):
     if mesh is not None:
         from repro.sharding.simplex import sharded_lattice_mvm
         return sharded_lattice_mvm(lat, v, weights, taps=taps,
@@ -302,19 +307,19 @@ def lattice_mvm(lat: Lattice, v: Array, weights: Array | None = None, *,
         weights = jnp.asarray(concrete, v.dtype)
 
     if backend == "fused_pallas":
+        # one kernel: splat, blur and slice share the ``simplex.mvm`` scope
         run_interp = (not _on_tpu()) if interpret is None else interpret
         return fused_filter_pallas(lat, v, concrete, symmetrize=symmetrize,
                                    transpose=transpose, interpret=run_interp)
     if backend == "per_direction_pallas":
-        splatted = lat_mod.splat(lat, v)
-        blurred = blur_pallas(lat, splatted, concrete, reverse=transpose,
-                              block_p=block_p, interpret=interpret)
-        if symmetrize:
-            blurred_r = blur_pallas(lat, splatted, concrete,
-                                    reverse=not transpose, block_p=block_p,
-                                    interpret=interpret)
-            blurred = 0.5 * (blurred + blurred_r)
-        return lat_mod.slice_(lat, blurred)
+        with obs.scope("mvm.splat"):
+            splatted = lat_mod.splat(lat, v)
+        blurred = _blur_both(
+            lambda t, rev: blur_pallas(lat, t, concrete, reverse=rev,
+                                       block_p=block_p, interpret=interpret),
+            splatted, symmetrize=symmetrize, transpose=transpose)
+        with obs.scope("mvm.slice"):
+            return lat_mod.slice_(lat, blurred)
     if backend == "fused_xla":
         return _fused_xla(lat, v, weights, symmetrize=symmetrize,
                           transpose=transpose)

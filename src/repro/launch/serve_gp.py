@@ -58,6 +58,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import json
 import pathlib
 import threading
@@ -68,6 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import filtering
 from repro.gp.models import GPParams, SimplexGP
 from repro.gp.serve import (Predictor, PredictorLoadError, load_predictor,
@@ -304,6 +306,7 @@ class GPServeEngine:
         self._persisted_version = 0
         self._persist_threads: list[threading.Thread] = []
         self._boot = {"mode": "cold", "generation": None, "skipped": 0}
+        self._query_ids = itertools.count(1)  # ties a query's spans
 
         # counters (guarded by _lock)
         self._c = collections.Counter()
@@ -414,9 +417,10 @@ class GPServeEngine:
 
         def work():
             try:
-                self._store.save(self._model_name, pred,
-                                 extra={"engine_version": version},
-                                 faults=self._faults)
+                with obs.span("refresh.persist", version=version):
+                    self._store.save(self._model_name, pred,
+                                     extra={"engine_version": version},
+                                     faults=self._faults)
                 with self._lock:
                     self._c["persists_ok"] += 1
                     self._persisted_version = max(self._persisted_version,
@@ -468,36 +472,45 @@ class GPServeEngine:
     def query(self, xs: Array, *, timeout_s: float | None = None,
               backend: str | None = None) -> QueryResult:
         """Serve one batch with bounded retry + deadline + fallback lane."""
+        qid = next(self._query_ids)
+        with obs.span("query", query_id=qid):
+            return self._query(xs, qid, timeout_s=timeout_s, backend=backend)
+
+    def _query(self, xs: Array, qid: int, *, timeout_s: float | None,
+               backend: str | None) -> QueryResult:
         cfg = self._cfg
         deadline = time.monotonic() + (cfg.query_timeout_s
                                        if timeout_s is None else timeout_s)
         attempts = 0
         while True:
-            with self._lock:
+            with obs.span("query.wait", query_id=qid), self._lock:
                 version = self._version
                 pred = self._registry[version]
                 stale = self._served_gen < self._data_gen
             try:
                 if self._faults is not None:
                     self._faults.maybe_raise("query")
-                sr = predict(pred, xs, backend=backend, mesh=self._mesh,
-                             axis_name=self._axis_name)
-                mean = np.asarray(sr.mean).astype(np.float32)
-                var = np.asarray(sr.var).astype(np.float32)
-                miss = np.asarray(sr.miss_mass)
-                # prior-fallback lane: a full-miss query's prediction IS
-                # the prior by the slicing math; make the contract
-                # explicit so a fallback response is prior-exact even if
-                # a future table format violates it
-                fb = miss >= cfg.fallback_miss
-                if fb.any():
-                    mean[fb] = 0.0
-                    var[fb] = float(pred.outputscale)
-                # zero-invalid-responses guarantee: the LAST line of
-                # defense behind the validation gate
-                if not (np.isfinite(mean).all() and np.isfinite(var).all()):
-                    raise RuntimeError(
-                        "non-finite response from a validated Predictor")
+                with obs.span("query.predict", query_id=qid):
+                    sr = predict(pred, xs, backend=backend, mesh=self._mesh,
+                                 axis_name=self._axis_name)
+                    mean = np.asarray(sr.mean).astype(np.float32)
+                    var = np.asarray(sr.var).astype(np.float32)
+                    miss = np.asarray(sr.miss_mass)
+                with obs.span("query.check", query_id=qid):
+                    # prior-fallback lane: a full-miss query's prediction
+                    # IS the prior by the slicing math; make the contract
+                    # explicit so a fallback response is prior-exact even
+                    # if a future table format violates it
+                    fb = miss >= cfg.fallback_miss
+                    if fb.any():
+                        mean[fb] = 0.0
+                        var[fb] = float(pred.outputscale)
+                    # zero-invalid-responses guarantee: the LAST line of
+                    # defense behind the validation gate
+                    if not (np.isfinite(mean).all()
+                            and np.isfinite(var).all()):
+                        raise RuntimeError(
+                            "non-finite response from a validated Predictor")
                 with self._lock:
                     self._c["queries_served"] += 1
                     self._c["fallback_queries"] += int(fb.sum())
@@ -583,6 +596,11 @@ class GPServeEngine:
 
     def _run_guarded(self, job: _RefreshJob) -> bool:
         """One refresh attempt under the wedge deadline; never raises."""
+        with obs.span("refresh", gen=job.gen) as attrs:
+            attrs["published"] = ok = self._attempt(job)
+        return ok
+
+    def _attempt(self, job: _RefreshJob) -> bool:
         self._refresh_idle = False
         result: dict = {}
 
@@ -665,10 +683,11 @@ class GPServeEngine:
         cand = None
         for attempt in range(cfg.max_cap_retries + 1):
             try:
-                cand = refreeze(model, params, x, job.y,
-                                key=self._next_key(), old=old,
-                                cache=self._cache, cap=cap,
-                                variance_rank=cfg.variance_rank)
+                with obs.span("refresh.freeze", gen=job.gen, cap=cap):
+                    cand = refreeze(model, params, x, job.y,
+                                    key=self._next_key(), old=old,
+                                    cache=self._cache, cap=cap,
+                                    variance_rank=cfg.variance_rank)
                 break
             except RuntimeError as e:
                 if ("capacity overflow" not in str(e)
@@ -685,8 +704,9 @@ class GPServeEngine:
             cand = dataclasses.replace(
                 cand, tables=faults.corrupt_tables("freeze", cand.tables))
 
-        rep = validate_predictor(cand,
-                                 require_converged=cfg.require_converged)
+        with obs.span("refresh.validate", gen=job.gen):
+            rep = validate_predictor(cand,
+                                     require_converged=cfg.require_converged)
         if not rep.ok:
             raise RefreshRejected("candidate refused: "
                                   + "; ".join(rep.failures))

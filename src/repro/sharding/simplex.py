@@ -49,6 +49,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import obs
 from repro.core.lattice import Lattice, slice_rows, splat_rows
 
 Array = jax.Array
@@ -134,33 +135,39 @@ def sharded_lattice_mvm(lat: Lattice, v: Array, weights: Array | None = None,
 
     def local_mvm(v_loc, seg_loc, bw_loc, nbr, w):
         # --- splat (local) + the ONE collective --------------------------
-        table = splat_rows(v_loc, seg_loc, bw_loc, cap + 1)
-        table = jax.lax.psum(table, axis_name)
+        with obs.scope("mvm.splat"):
+            table = splat_rows(v_loc, seg_loc, bw_loc, cap + 1)
+        with obs.scope("mvm.psum"):
+            table = jax.lax.psum(table, axis_name)
         table = table.at[cap].set(0.0)
 
         # --- blur (replicated on the summed table) -----------------------
-        w_off = jnp.concatenate([w[:r], w[r + 1:]])  # (2r,) off-center taps
+        with obs.scope("mvm.blur"):
+            w_off = jnp.concatenate([w[:r], w[r + 1:]])  # (2r,) off-center
 
-        def blur_dir(vals, a):
-            out = vals * w[r] + jnp.einsum("prc,r->pc", vals[nbr[a]], w_off)
-            return out.at[cap].set(0.0), None
+            def blur_dir(vals, a):
+                out = vals * w[r] + jnp.einsum("prc,r->pc", vals[nbr[a]],
+                                               w_off)
+                return out.at[cap].set(0.0), None
 
-        order = jnp.arange(d1)
-        fwd = order[::-1] if transpose else order
-        blurred, _ = jax.lax.scan(blur_dir, table, fwd)
-        if symmetrize:  # 0.5 (F + F^T): same summed table, opposite sweep
-            blurred_r, _ = jax.lax.scan(blur_dir, table, fwd[::-1])
-            blurred = 0.5 * (blurred + blurred_r)
+            order = jnp.arange(d1)
+            fwd = order[::-1] if transpose else order
+            blurred, _ = jax.lax.scan(blur_dir, table, fwd)
+            if symmetrize:  # 0.5 (F + F^T): same summed table, opposite sweep
+                blurred_r, _ = jax.lax.scan(blur_dir, table, fwd[::-1])
+                blurred = 0.5 * (blurred + blurred_r)
 
         # --- slice (local) ----------------------------------------------
-        return slice_rows(blurred, seg_loc, bw_loc)
+        with obs.scope("mvm.slice"):
+            return slice_rows(blurred, seg_loc, bw_loc)
 
     fn = shard_map(
         local_mvm, mesh=mesh,
         in_specs=(P(axis_name, None), P(axis_name, None),
                   P(axis_name, None), P(), P()),
         out_specs=P(axis_name, None))
-    out = fn(v, seg, bary, lat.nbr, weights.astype(v.dtype))
+    with obs.scope("mvm"):
+        out = fn(v, seg, bary, lat.nbr, weights.astype(v.dtype))
     return out[:n] if ghost else out
 
 

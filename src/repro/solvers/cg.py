@@ -22,6 +22,8 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 Array = jax.Array
 MatVec = Callable[[Array], Array]  # (n, k) -> (n, k)
 
@@ -63,6 +65,7 @@ def _seed_state(matvec: MatVec, b: Array,
     return x, r
 
 
+@obs.scope("cg")
 def cg(
     matvec: MatVec,
     b: Array,
@@ -79,9 +82,9 @@ def cg(
     iteration issues exactly ONE ``matvec`` on the full (n, k) block
     (never one per column), so with a lattice operator every iteration
     costs one batched lattice MVM regardless of how many probes ride
-    along. ``kernels.blur.ops.mvm_count``/``mvm_cols`` instrument this
-    (tests/test_solvers.py pins it); sharded operators (DESIGN.md §10)
-    then also pay one psum per iteration, not k.
+    along. The ``mvm.calls``/``mvm.cols`` counters of ``repro.obs``
+    instrument this (tests/test_solvers.py pins it); sharded operators
+    (DESIGN.md §10) then also pay one psum per iteration, not k.
 
     Args:
       matvec: ``v -> A v`` over (n, k) blocks.
@@ -152,6 +155,7 @@ def cg(
     return x, info
 
 
+@obs.scope("cg")
 def cg_while(
     matvec: MatVec,
     b: Array,

@@ -18,6 +18,8 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 Array = jax.Array
 MatVec = Callable[[Array], Array]  # (n, k) -> (n, k)
 
@@ -29,6 +31,7 @@ class LanczosResult(NamedTuple):
     valid: Array  # (iters, k) True while the recurrence was healthy
 
 
+@obs.scope("lanczos")
 def lanczos(matvec: MatVec, q0: Array, num_iters: int,
             *, reorthogonalize: bool = True) -> LanczosResult:
     """Block-of-columns Lanczos with optional full reorthogonalization.
@@ -92,6 +95,7 @@ def slq_quadrature(alphas: Array, betas: Array, valid: Array,
     return jax.vmap(one, in_axes=(1, 1, 1))(alphas, betas, valid)
 
 
+@obs.scope("slq")
 def slq_logdet(matvec: MatVec, n: int, *, key: Array, num_probes: int = 10,
                num_iters: int = 100, dtype=jnp.float32) -> Array:
     """SLQ estimate of log|A| for SPD A of size n."""
@@ -103,6 +107,7 @@ def slq_logdet(matvec: MatVec, n: int, *, key: Array, num_probes: int = 10,
     return jnp.mean(znorm2 * quad)
 
 
+@obs.scope("slq")
 def slq_logdet_from_cg(alphas: Array, betas: Array, valid: Array,
                        probe_norms2: Array) -> Array:
     """Log-det estimate reusing mBCG's tridiagonal coefficients.

@@ -18,6 +18,8 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 Array = jax.Array
 
 
@@ -27,6 +29,7 @@ class PivotedCholesky(NamedTuple):
     error: Array  # () trace of the residual diagonal
 
 
+@obs.scope("precond")
 def pivoted_cholesky(row_fn: Callable[[Array], Array], diag: Array,
                      rank: int) -> PivotedCholesky:
     """Greedy rank-`rank` Cholesky. row_fn(i) -> K[i, :] (length n)."""

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.lattice import build_count
+from repro import obs
 from repro.gp import (GPParams, SimplexGP, SimplexGPConfig,
                       mll_value_and_grad, posterior)
 
@@ -33,9 +33,10 @@ def test_training_step_smoke(rng):
     params = GPParams.init(d)
 
     step = jax.jit(lambda p, k: mll_value_and_grad(model, p, x, y, k))
-    c0 = build_count()
+    c0 = obs.counter("lattice.builds")
     res = jax.block_until_ready(step(params, jax.random.PRNGKey(0)))
-    assert build_count() - c0 == 1  # one lattice build per training step
+    # one lattice build per training step
+    assert obs.counter("lattice.builds") - c0 == 1
     assert np.isfinite(float(res.mll))
     assert not bool(res.overflow)
     for leaf in jax.tree.leaves(res.grads):
@@ -52,11 +53,12 @@ def test_posterior_smoke(rng):
                                       backend="auto"))
     params = GPParams.init(d)
 
-    c0 = build_count()
+    c0 = obs.counter("lattice.builds")
     post = posterior(model, params, x, y, xs, key=jax.random.PRNGKey(1),
                      variance_rank=6)
     jax.block_until_ready(post.mean)
-    assert build_count() - c0 == 1  # one lattice build per posterior
+    # one lattice build per posterior
+    assert obs.counter("lattice.builds") - c0 == 1
     assert post.mean.shape == (ns,)
     assert bool(jnp.all(jnp.isfinite(post.mean)))
     assert bool(jnp.all(post.var > 0))

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import filtering, kernels_math as km
 from repro.core.lattice import build_lattice
 from repro.core.stencil import make_stencil
@@ -215,7 +216,6 @@ def test_lattice_filter_with_matches_rebuild(rng):
 def test_lattice_filter_with_jit_traced_lattice(rng):
     """The prebuilt-lattice VJP works with the lattice as a traced pytree
     (the in-jit training-step usage) and performs zero builds."""
-    from repro.core.lattice import build_count
 
     x, v = _data(rng, 150, 3)
     g = jnp.asarray(rng.normal(size=v.shape), jnp.float32)
@@ -230,9 +230,9 @@ def test_lattice_filter_with_jit_traced_lattice(rng):
         return jax.grad(lambda zz: jnp.vdot(g, filtering.lattice_filter_with(
             lt, zz, vv, w, dw, spec)))(z)
 
-    c0 = build_count()
+    c0 = obs.counter("lattice.builds")
     dz = grad_z(lat, x, v)
-    assert build_count() - c0 == 0
+    assert obs.counter("lattice.builds") - c0 == 0
     want = jax.grad(lambda zz: jnp.vdot(g, filtering.lattice_filter(
         zz, v, w, dw, spec)))(x)
     np.testing.assert_allclose(np.asarray(dz), np.asarray(want),
@@ -242,7 +242,6 @@ def test_lattice_filter_with_jit_traced_lattice(rng):
 def test_lattice_cache_reuses_builds(rng):
     """Same concrete (point set, lengthscale, spacing, r, cap) -> one build;
     any key change -> fresh build; traced inputs bypass the memo."""
-    from repro.core.lattice import build_count
 
     x, _ = _data(rng, 120, 3)
     st = make_stencil("rbf", 1)
@@ -250,18 +249,18 @@ def test_lattice_cache_reuses_builds(rng):
     tag = cache.point_set_tag(x)
     ls = jnp.ones((3,), jnp.float32)
 
-    c0 = build_count()
+    c0 = obs.counter("lattice.builds")
     l1 = cache.get(tag, x, spacing=st.spacing, r=st.r, cap=None, ls=ls)
     l2 = cache.get(tag, x, spacing=st.spacing, r=st.r, cap=None, ls=ls)
     assert l1 is l2
-    assert build_count() - c0 == 1
+    assert obs.counter("lattice.builds") - c0 == 1
     assert cache.hits == 1 and cache.misses == 1
 
     # lengthscale moved -> rebuild
     l3 = cache.get(tag, x, spacing=st.spacing, r=st.r, cap=None,
                    ls=2.0 * ls)
     assert l3 is not l1
-    assert build_count() - c0 == 2
+    assert obs.counter("lattice.builds") - c0 == 2
 
     # traced lengthscale -> bypass (fresh build, nothing cached)
     jax.jit(lambda s: cache.get(tag, x, spacing=st.spacing, r=st.r,

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import kernels_math as km
 from repro.core.exact import ExactGP
 from repro.gp import (GPParams, SimplexGP, SimplexGPConfig, cross_mvm, fit,
@@ -82,7 +83,6 @@ def test_one_lattice_build_per_step_and_posterior(rng):
     DESIGN.md §10 merged the two surrogate terms into one filtering even
     without lattice sharing); its posterior still builds 3 (operator + two
     cross_mvm joint builds)."""
-    from repro.core.lattice import build_count
 
     x, y, _ = _problem(rng, n=300)
     xs, _, _ = _problem(np.random.default_rng(5), n=60)
@@ -97,15 +97,15 @@ def test_one_lattice_build_per_step_and_posterior(rng):
     for model, want_step, want_post in [(shared, 1, 1), (legacy, 2, 3)]:
         step = jax.jit(lambda p, k, m=model: mll_value_and_grad(
             m, p, x, y, k))
-        c0 = build_count()
+        c0 = obs.counter("lattice.builds")
         jax.block_until_ready(step(params, jax.random.PRNGKey(0)))
-        assert build_count() - c0 == want_step
+        assert obs.counter("lattice.builds") - c0 == want_step
 
-        c0 = build_count()
+        c0 = obs.counter("lattice.builds")
         post = posterior(model, params, x, y, xs,
                          key=jax.random.PRNGKey(1), variance_rank=8)
         jax.block_until_ready(post.mean)
-        assert build_count() - c0 == want_post
+        assert obs.counter("lattice.builds") - c0 == want_post
 
 
 def test_shared_lattice_matches_legacy_pipeline(rng):

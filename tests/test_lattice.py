@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import lattice as L
 
 DIMS = [1, 2, 3, 5, 8, 11]
@@ -137,10 +138,10 @@ def test_pack_unpack_roundtrip(rng, d):
 
 def test_build_count_increments(rng):
     x = _points(rng, 40, 3)
-    c0 = L.build_count()
+    c0 = obs.counter("lattice.builds")
     L.build_lattice(x, spacing=1.0, r=1)
     L.build_lattice(x, spacing=1.0, r=1)
-    assert L.build_count() - c0 == 2
+    assert obs.counter("lattice.builds") - c0 == 2
 
 
 def test_pack_overflow_flag_distinct_from_capacity(rng):

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import filtering
 from repro.core import lattice as L
 from repro.gp import (GPParams, SimplexGP, SimplexGPConfig, freeze,
@@ -187,13 +188,13 @@ def test_freeze_respects_cache_and_cap(rng):
     params = GPParams.init(3)
     key = jax.random.PRNGKey(0)
     cache = filtering.LatticeCache()
-    c0 = L.build_count()
+    c0 = obs.counter("lattice.builds")
     freeze(model, params, x, y, key=key, variance_rank=6, cap=2048,
            cache=cache)
     freeze(model, params, x, y, key=key, variance_rank=6, cap=2048,
            cache=cache)
     assert cache.hits == 1 and cache.misses == 1
-    assert L.build_count() - c0 == 1
+    assert obs.counter("lattice.builds") - c0 == 1
 
 
 def test_freeze_raises_on_overflowed_lattice(rng):

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import filtering
 from repro.core import lattice as L
 from repro.core.kernels_math import PROFILES
@@ -281,16 +282,16 @@ def test_multi_channel_serving_embeds_once():
     mp = freeze_multi(model, params, x, ys, key=key, variance_rank=rank,
                       cap=cap)
     xs = x[:16]
-    before = L.embed_count()
+    before = obs.counter("lattice.embeds")
     jax.make_jaxpr(
         lambda q: _predict_multi_core(mp, q, backend="slice_xla"))(xs)
-    assert L.embed_count() - before == 1
+    assert obs.counter("lattice.embeds") - before == 1
     # and the gradient pass too: one ranked embed serves primal + Jacobian
-    before = L.embed_count()
+    before = obs.counter("lattice.embeds")
     jax.make_jaxpr(
         lambda q: filtering.slice_only_grad(mp.index, mp.tables,
                                             q, spacing=mp.spacing))(xs)
-    assert L.embed_count() - before == 1
+    assert obs.counter("lattice.embeds") - before == 1
 
 
 # -- boundary / tie-break semantics ------------------------------------------

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import kernels_math as km
 from repro.solvers import (cg, cg_while, expected_iters, lanczos,
                            pivoted_cholesky, precond_logdet, rrcg,
@@ -195,13 +196,12 @@ def test_mbcg_issues_one_batched_mvm_per_iteration(rng):
     """Multi-RHS operator contract: mBCG with k probe columns advances the
     whole [y | Z] block through ONE (n, 1+k)-channel lattice MVM per
     iteration — never one MVM per column. Pinned at trace level with the
-    kernels/blur/ops instrumentation (build_count-style): the CG scan body
+    ``mvm.calls``/``mvm.cols`` counters of ``repro.obs``: the CG scan body
     traces exactly one lattice_mvm call whose channel width is the full
     block, and the operator build itself traces exactly one more (the
     initial residual is b, so there is no extra setup MVM)."""
     from repro.core import filtering
     from repro.core.stencil import make_stencil
-    from repro.kernels.blur.ops import mvm_cols, mvm_count
 
     n, d, k = 96, 2, 7
     x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
@@ -210,10 +210,10 @@ def test_mbcg_issues_one_batched_mvm_per_iteration(rng):
     matvec, _ = filtering.mvm_operator(x, stn)
     op = lambda v: matvec(v) + 0.5 * v
 
-    c0, w0 = mvm_count(), mvm_cols()
+    c0, w0 = obs.counter("mvm.calls"), obs.counter("mvm.cols")
     _, info = cg(op, b, tol=1e-2, max_iters=25)
-    calls = mvm_count() - c0
-    cols = mvm_cols() - w0
+    calls = obs.counter("mvm.calls") - c0
+    cols = obs.counter("mvm.cols") - w0
     assert calls == 1, calls  # one traced MVM in the scan body
     assert cols == 1 + k, cols  # ... carrying the WHOLE block
     assert int(info.iterations) > 1  # and it actually iterated
@@ -224,7 +224,6 @@ def test_lanczos_block_rides_one_mvm_per_iteration(rng):
     tridiagonalized with one batched MVM per iteration."""
     from repro.core import filtering
     from repro.core.stencil import make_stencil
-    from repro.kernels.blur.ops import mvm_cols, mvm_count
 
     n, d, k = 80, 2, 5
     x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
@@ -232,8 +231,8 @@ def test_lanczos_block_rides_one_mvm_per_iteration(rng):
     stn = make_stencil("rbf", 1)
     matvec, _ = filtering.mvm_operator(x, stn)
 
-    c0, w0 = mvm_count(), mvm_cols()
+    c0, w0 = obs.counter("mvm.calls"), obs.counter("mvm.cols")
     res = lanczos(lambda v: matvec(v) + 0.1 * v, q0, 10)
-    assert mvm_count() - c0 == 1
-    assert mvm_cols() - w0 == k
+    assert obs.counter("mvm.calls") - c0 == 1
+    assert obs.counter("mvm.cols") - w0 == k
     assert bool(jnp.all(jnp.isfinite(res.alphas)))
