@@ -45,6 +45,7 @@ class Posterior(NamedTuple):
     var: Array  # (n*,) latent-f variance (add noise for predictive y)
     overflow: Array | bool = False  # lattice table overflow flag
     pack_overflow: Array | bool = False  # coord range overflow (can't grow)
+    cg_iters: Array | int = 0  # iterations the mean solve used
 
 
 def _joint_lattice(model: SimplexGP, params: GPParams, x: Array, xs: Array,
@@ -147,8 +148,8 @@ def posterior(model: SimplexGP, params: GPParams, x: Array, y: Array,
                 + noise * v)
 
     # mean solve
-    u, _ = cg_solve(mvm, y[:, None], tol=cfg.cg_tol_eval,
-                     max_iters=cfg.max_cg_iters)
+    u, info = cg_solve(mvm, y[:, None], tol=cfg.cg_tol_eval,
+                       max_iters=cfg.max_cg_iters)
 
     # variance via Lanczos on K_hat (LOVE-style)
     q0 = y[:, None] + 1e-3 * jax.random.normal(key, (n, 1), x.dtype)
@@ -161,7 +162,8 @@ def posterior(model: SimplexGP, params: GPParams, x: Array, y: Array,
                       lat=lat, mesh=mesh)
     var = _love_var(tdense, ksall[:, 1:], os_)
     return Posterior(mean=ksall[:, 0], var=var, overflow=lat.overflow,
-                     pack_overflow=lat.pack_overflow)
+                     pack_overflow=lat.pack_overflow,
+                     cg_iters=info.iterations)
 
 
 def _posterior_rebuild(model: SimplexGP, params: GPParams, x: Array,
@@ -173,8 +175,8 @@ def _posterior_rebuild(model: SimplexGP, params: GPParams, x: Array,
     cfg = model.config
     op = model.operator(params, x)
 
-    u, _ = cg_solve(op.mvm, y[:, None], tol=cfg.cg_tol_eval,
-                     max_iters=cfg.max_cg_iters)
+    u, info = cg_solve(op.mvm, y[:, None], tol=cfg.cg_tol_eval,
+                       max_iters=cfg.max_cg_iters)
     mean = cross_mvm(model, params, x, xs, u)[:, 0]
 
     q0 = y[:, None] + 1e-3 * jax.random.normal(key, (x.shape[0], 1), x.dtype)
@@ -183,7 +185,8 @@ def _posterior_rebuild(model: SimplexGP, params: GPParams, x: Array,
     return Posterior(mean=mean,
                      var=_love_var(_love_tridiag(lres), ksq, op.outputscale),
                      overflow=op.lattice.overflow,
-                     pack_overflow=op.lattice.pack_overflow)
+                     pack_overflow=op.lattice.pack_overflow,
+                     cg_iters=info.iterations)
 
 
 def exact_mean_grad(profile, x: Array, y: Array, xs: Array, *,

@@ -53,7 +53,7 @@ from repro.core.lattice import LatticeIndex
 from repro.gp.models import GPParams, SimplexGP
 from repro.runtime.checkpoint import (CheckpointCorruptError, load_blobs,
                                       read_manifest, save_blobs)
-from repro.solvers.cg import cg_while as cg_solve
+from repro.solvers.cg import cg as cg_solve
 from repro.solvers.lanczos import lanczos as lanczos_run
 
 Array = jax.Array

@@ -272,7 +272,7 @@ def fit(model: SimplexGP, x: Array, y: Array, *, x_val: Array, y_val: Array,
                                          key=key, variance_rank=10,
                                          cap=cap_val, mesh=mesh)
             return (predict_mod.rmse(post, y_val), post.overflow,
-                    post.pack_overflow)
+                    post.pack_overflow, post.cg_iters)
         return val_rmse
 
     def _check_pack(povf):
@@ -411,10 +411,11 @@ def fit(model: SimplexGP, x: Array, y: Array, *, x_val: Array, y_val: Array,
                                    "left)")
                         continue
                     raise
-                with obs.span("step.read"):
+                with obs.span("step.read") as attrs:
                     _check_pack(povf)
                     overflowed = bool(ovf)
                     mll, iters, gok = float(mll), int(iters), bool(gok)
+                    attrs["cg_iters"] = iters
                 if not overflowed or st.cap >= worst:
                     break
                 st.cap = min(st.cap * CAP_GROWTH, worst)  # stale grads: regrow
@@ -448,19 +449,21 @@ def fit(model: SimplexGP, x: Array, y: Array, *, x_val: Array, y_val: Array,
 
             st.params, st.opt_state = new_params, new_state
             dt = time.perf_counter() - t0
-            with obs.span("validation"):
+            with obs.span("validation") as attrs:
                 while True:
-                    rmse_v, ovf, povf = val_rmse(st.params, k2)
+                    rmse_v, ovf, povf, val_iters = val_rmse(st.params, k2)
                     _check_pack(povf)
                     if not bool(ovf) or st.cap_val >= worst_joint:
                         break
                     st.cap_val = min(st.cap_val * CAP_GROWTH, worst_joint)
                     with obs.span("regrow", cap_val=st.cap_val):
                         val_rmse = make_val(st.cap_val)
-                rmse = float(rmse_v)
+                rmse, val_iters = float(rmse_v), int(val_iters)
+                attrs["cg_iters"] = val_iters
             st.window = (st.window + [loss])[-spike_window:]
             history.append(dict(epoch=epoch, mll=mll, val_rmse=rmse,
-                                cg_iters=iters, seconds=dt, cap=st.cap))
+                                cg_iters=iters, val_cg_iters=val_iters,
+                                seconds=dt, cap=st.cap))
             if log_fn:
                 log_fn(f"epoch {epoch:3d}  mll/n {mll / x.shape[0]:+.4f}  "
                        f"val_rmse {rmse:.4f}  cg_iters {iters}  {dt:.2f}s")

@@ -1,5 +1,5 @@
 """Krylov solvers for MVM-based GP inference (BBMM)."""
-from repro.solvers.cg import CGInfo, cg, cg_while, lanczos_tridiag_from_cg
+from repro.solvers.cg import CGInfo, cg, lanczos_tridiag_from_cg
 from repro.solvers.lanczos import (LanczosResult, lanczos, slq_logdet,
                                    slq_logdet_from_cg, slq_quadrature)
 from repro.solvers.pivoted_cholesky import (PivotedCholesky, pivoted_cholesky,
@@ -7,7 +7,7 @@ from repro.solvers.pivoted_cholesky import (PivotedCholesky, pivoted_cholesky,
 from repro.solvers.rrcg import RRCGResult, expected_iters, rrcg
 
 __all__ = [
-    "CGInfo", "cg", "cg_while", "lanczos_tridiag_from_cg",
+    "CGInfo", "cg", "lanczos_tridiag_from_cg",
     "LanczosResult", "lanczos", "slq_logdet", "slq_logdet_from_cg",
     "slq_quadrature",
     "PivotedCholesky", "pivoted_cholesky", "precond_logdet",

@@ -8,12 +8,14 @@ right-hand-sides ``B = [y | z_1 .. z_p]`` that simultaneously
   * collects the Lanczos tridiagonal coefficients (alpha, beta) per column,
     which SLQ (solvers/lanczos.py) turns into a log-det estimate "for free".
 
-TPU notes: the loop is a ``lax.scan`` over a *static* ``max_iters`` with a
-convergence mask that freezes finished columns — dynamic trip counts do not
-exist on TPU, and a scan keeps the HLO a single While op so the 40-cell
-dry-run stays compilable. The mask also reproduces the paper's "CG error
-tolerance" semantics (Appendix A: tol 1.0 train / 0.01 eval): a column stops
-updating once ``||r|| <= tol * ||b||``.
+The loop is a ``lax.while_loop`` that exits on the residuals: a column
+stops once ``||r|| <= tol * ||b||`` (the paper's "CG error tolerance",
+Appendix A: tol 1.0 train / 0.01 eval) after the ``min_iters`` floor, and
+the loop stops once every column has, or at ``max_iters``. Each trip
+costs one MVM on the whole block, so a solve pays for the iterations its
+stopping rule uses, not for ``max_iters``. The coefficient buffers keep the
+static ``(max_iters, k)`` shape; rows past a column's exit hold alpha =
+beta = 0 and valid = False, what a frozen trip would have written.
 """
 from __future__ import annotations
 
@@ -91,13 +93,17 @@ def cg(
       b: (n, k) right-hand sides.
       precond: ``v -> P^{-1} v`` (SPD); None = identity.
       tol: relative residual tolerance (paper Appendix A: 1.0 train / 0.01 eval).
-      max_iters: static scan length (paper Appendix A: 500).
+      max_iters: the most iterations (paper Appendix A: 500); also the
+        static row count of the coefficient buffers.
       min_iters: iterations always run before the tolerance may stop a
         column (GPyTorch semantics — at the paper's train tolerance 1.0 the
         *initial* relative residual is exactly 1, so without a floor CG
         would do nothing; GPyTorch's 10-iteration floor is what actually
         does the work at tol=1).
-      x0: optional initial guess.
+      x0: optional warm start (gp/serve.refreeze seeds from the previous
+        Predictor's alpha). Seeds pass ``_seed_state`` hygiene, and a
+        column whose seed is already within ``tol`` starts inactive, so a
+        perfect seed costs one matvec and no iteration.
 
     Returns:
       x: (n, k) approximate solves.
@@ -117,9 +123,19 @@ def cg(
     bnorm = jnp.maximum(jnp.linalg.norm(b, axis=0), 1e-30)
     tol_arr = jnp.asarray(tol, dt)
     min_iters = min(min_iters, max_iters)
+    # a cold start must enter the loop even at tol >= 1 (the min_iters
+    # contract); a warm start may skip columns its seed already solved
+    if x0 is None:
+        active0 = jnp.ones((k,), bool)
+    else:
+        active0 = jnp.linalg.norm(r, axis=0) / bnorm > tol_arr
 
-    def body(carry, j):
-        x, r, z, p, rz, active = carry
+    def cond(state):
+        j, *_rest, active, _coefs = state
+        return (j < max_iters) & jnp.any(active)
+
+    def body(state):
+        j, x, r, z, p, rz, active, (alphas, betas, valid) = state
         ap = matvec(p)
         pap = jnp.sum(p * ap, axis=0)
         # guard: inactive / degenerate columns get alpha = 0 (no update)
@@ -134,114 +150,25 @@ def cg(
         p = z + beta * p
         res = jnp.linalg.norm(r, axis=0) / bnorm
         still = active & ((res > tol_arr) | (j + 1 < min_iters))
-        out = (alpha, beta, active)
-        return (x, r, z, p, rz_new, still), out
+        coefs = tuple(jax.lax.dynamic_update_index_in_dim(buf, row, j, 0)
+                      for buf, row in ((alphas, alpha), (betas, beta),
+                                       (valid, active)))
+        return (j + 1, x, r, z, p, rz_new, still, coefs)
 
-    active0 = jnp.ones((k,), bool)
-    init = (x, r, z, p, rz, active0)
-    (x, r, *_rest), (alphas, betas, valids) = jax.lax.scan(
-        body, init, jnp.arange(max_iters))
-
-    res = jnp.linalg.norm(r, axis=0) / bnorm
-    iters = jnp.sum(jnp.any(valids, axis=1).astype(jnp.int32))
-    info = CGInfo(
-        iterations=iters,
-        residual_norms=res,
-        converged=res <= tol_arr,
-        alphas=alphas,
-        betas=betas,
-        valid=valids,
-    )
-    return x, info
-
-
-@obs.scope("cg")
-def cg_while(
-    matvec: MatVec,
-    b: Array,
-    *,
-    precond: MatVec | None = None,
-    tol: float | Array = 1e-2,
-    max_iters: int = 500,
-    min_iters: int = 10,
-    x0: Array | None = None,
-) -> tuple[Array, CGInfo]:
-    """Early-exit CG twin of ``cg`` for WARM-STARTED solves (no SLQ).
-
-    The scan-based ``cg`` runs its static ``max_iters`` trip count even
-    after every column converges (frozen columns just stop updating) —
-    the right trade when the Lanczos coefficients are wanted for SLQ and
-    the solve is cold. A warm-started solve (gp/serve.refreeze seeding
-    from the previous Predictor's alpha) converges in a handful of
-    iterations, so here the loop is a ``lax.while_loop`` that exits as
-    soon as every column is done — the wall-clock win warm starting is
-    for. Columns whose ``x0`` residual is already within ``tol`` start
-    INACTIVE (zero iterations), so a perfect seed costs one matvec.
-    Seeds pass through ``_seed_state`` hygiene first: non-finite entries
-    are zeroed and regressive columns fall back to the cold start, so an
-    alpha restored from an old checkpoint (the warm-boot path) can only
-    help, never hurt.
-
-    Same operator/stopping semantics as ``cg`` (identical iterates while
-    active, same ``min_iters`` refinement floor for active columns); the
-    returned ``CGInfo`` carries real iteration/residual/convergence
-    diagnostics but EMPTY (0, k) Lanczos coefficient arrays — use ``cg``
-    when SLQ needs them.
-    """
-    if b.ndim == 1:
-        raise ValueError("cg_while expects (n, k) column-blocked RHS; "
-                         "got 1-D")
-    minv = precond or _identity_precond
-    n, k = b.shape
-    dt = b.dtype
-
-    x, r = _seed_state(matvec, b, x0)
-    z = minv(r)
-    p = z
-    rz = jnp.sum(r * z, axis=0)
-    bnorm = jnp.maximum(jnp.linalg.norm(b, axis=0), 1e-30)
-    tol_arr = jnp.asarray(tol, dt)
-    min_iters = min(min_iters, max_iters)
-    # a cold start must enter the loop even at tol >= 1 (the min_iters
-    # contract); a warm start may skip columns its seed already solved
-    if x0 is None:
-        active0 = jnp.ones((k,), bool)
-    else:
-        active0 = jnp.linalg.norm(r, axis=0) / bnorm > tol_arr
-
-    def cond(state):
-        j, *_rest, active = state
-        return (j < max_iters) & jnp.any(active)
-
-    def body(state):
-        j, x, r, z, p, rz, active = state
-        ap = matvec(p)
-        pap = jnp.sum(p * ap, axis=0)
-        safe_pap = jnp.where(pap > 0, pap, 1.0)
-        alpha = jnp.where(active & (pap > 0), rz / safe_pap, 0.0)
-        x = x + alpha * p
-        r = r - alpha * ap
-        z = minv(r)
-        rz_new = jnp.sum(r * z, axis=0)
-        safe_rz = jnp.where(rz != 0, rz, 1.0)
-        beta = jnp.where(active, rz_new / safe_rz, 0.0)
-        p = z + beta * p
-        res = jnp.linalg.norm(r, axis=0) / bnorm
-        still = active & ((res > tol_arr) | (j + 1 < min_iters))
-        return (j + 1, x, r, z, p, rz_new, still)
-
-    state = (jnp.zeros((), jnp.int32), x, r, z, p, rz, active0)
-    j, x, r, *_rest = jax.lax.while_loop(cond, body, state)
+    coefs0 = (jnp.zeros((max_iters, k), dt), jnp.zeros((max_iters, k), dt),
+              jnp.zeros((max_iters, k), bool))
+    state = (jnp.zeros((), jnp.int32), x, r, z, p, rz, active0, coefs0)
+    j, x, r, *_rest, (alphas, betas, valid) = jax.lax.while_loop(
+        cond, body, state)
 
     res = jnp.linalg.norm(r, axis=0) / bnorm
-    empty = jnp.zeros((0, k), dt)
     info = CGInfo(
         iterations=j,
         residual_norms=res,
         converged=res <= tol_arr,
-        alphas=empty,
-        betas=empty,
-        valid=jnp.zeros((0, k), bool),
+        alphas=alphas,
+        betas=betas,
+        valid=valid,
     )
     return x, info
 

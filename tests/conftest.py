@@ -1,5 +1,7 @@
+import itertools
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -29,6 +31,23 @@ MULTIDEVICE_XLA_FLAGS = (
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def cg_loop():
+    """Compiled HLO text -> (the CG ``while`` line, the lines of its
+    condition computation). There must be exactly one CG loop."""
+    def find(text):
+        lines = text.splitlines()
+        loops = [ln for ln in lines
+                 if " while(" in ln and 'simplex.cg/while"' in ln]
+        assert len(loops) == 1, loops
+        cond = re.search(r"condition=(%[\w.\-]+)", loops[0]).group(1)
+        start = next(i for i, ln in enumerate(lines)
+                     if ln.startswith(cond + " "))
+        body = itertools.takewhile(lambda ln: ln != "}", lines[start + 1:])
+        return loops[0], list(body)
+    return find
 
 
 @pytest.fixture

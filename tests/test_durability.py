@@ -37,7 +37,7 @@ from repro.runtime.checkpoint import (CheckpointCorruptError,
                                       save_blobs)
 from repro.runtime.faults import (CORRUPTION_KINDS, FaultInjector,
                                   InjectedFault, corrupt_checkpoint)
-from repro.solvers import cg_while
+from repro.solvers import cg
 
 # the benchmarks package lives at the repo root (not under src/)
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -400,9 +400,9 @@ def _spd_problem(rng, n=48, k=3):
 
 def test_cg_nonfinite_seed_sanitized(rng):
     matvec, b = _spd_problem(rng)
-    x_ref, _ = cg_while(matvec, b, tol=1e-6, max_iters=200)
+    x_ref, _ = cg(matvec, b, tol=1e-6, max_iters=200)
     bad = jnp.full_like(b, jnp.nan).at[:, 0].set(b[:, 0])
-    x, info = cg_while(matvec, b, tol=1e-6, max_iters=200, x0=bad)
+    x, info = cg(matvec, b, tol=1e-6, max_iters=200, x0=bad)
     assert bool(jnp.all(jnp.isfinite(x)))
     assert bool(jnp.all(info.converged))
     np.testing.assert_allclose(np.asarray(x), np.asarray(x_ref),
@@ -413,17 +413,17 @@ def test_cg_regressive_seed_reset_to_cold(rng):
     """A seed WORSE than zero (stale checkpoint under new hyperparams)
     must not slow convergence below the cold start."""
     matvec, b = _spd_problem(rng)
-    _, cold = cg_while(matvec, b, tol=1e-6, max_iters=200)
+    _, cold = cg(matvec, b, tol=1e-6, max_iters=200)
     awful = 1e6 * jnp.ones_like(b)
-    x, info = cg_while(matvec, b, tol=1e-6, max_iters=200, x0=awful)
+    x, info = cg(matvec, b, tol=1e-6, max_iters=200, x0=awful)
     assert bool(jnp.all(info.converged))
     assert int(info.iterations) <= int(cold.iterations)
 
 
 def test_cg_perfect_seed_costs_zero_iterations(rng):
     matvec, b = _spd_problem(rng)
-    x_ref, _ = cg_while(matvec, b, tol=1e-6, max_iters=200)
-    _, info = cg_while(matvec, b, tol=1e-4, max_iters=200, x0=x_ref)
+    x_ref, _ = cg(matvec, b, tol=1e-6, max_iters=200)
+    _, info = cg(matvec, b, tol=1e-4, max_iters=200, x0=x_ref)
     assert int(info.iterations) == 0
 
 

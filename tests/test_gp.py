@@ -59,6 +59,22 @@ def test_posterior_beats_prior(rng):
     assert np.isfinite(float(nll(post, model.constrained(params)[2], fs)))
 
 
+@pytest.mark.parametrize("shared", [True, False])
+def test_posterior_reports_mean_solve_iterations(rng, shared):
+    """``Posterior.cg_iters`` is the mean solve's iteration count: at the
+    eval tolerance it stops well before ``max_cg_iters``."""
+    x, y, _ = _problem(rng, n=400)
+    xs, _, _ = _problem(np.random.default_rng(3), n=50)
+    model = SimplexGP(SimplexGPConfig(kernel="matern32", max_cg_iters=60,
+                                      shared_lattice=shared))
+    params = GPParams.init(3, noise=0.1, lengthscale=1.0)
+    post = jax.jit(lambda p: posterior(model, p, x, y, xs,
+                                       key=jax.random.PRNGKey(1),
+                                       variance_rank=5))(params)
+    assert post.cg_iters.dtype == jnp.int32
+    assert 0 < int(post.cg_iters) < 60
+
+
 def test_cross_mvm_matches_dense(rng):
     x = jnp.asarray(rng.normal(size=(300, 3)), jnp.float32)
     xs = jnp.asarray(rng.normal(size=(80, 3)), jnp.float32)

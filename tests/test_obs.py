@@ -219,6 +219,12 @@ def test_fit_records_epoch_step_and_validation_spans():
             assert s.parent_id in ids
     assert all(s.attrs["tries"] >= 1 for s in spans
                if s.name == "build_auto")
+    # both CG counts ride on the spans and in the history
+    for name, key in (("step.read", "cg_iters"),
+                      ("validation", "val_cg_iters")):
+        got = [s.attrs["cg_iters"] for s in spans if s.name == name]
+        assert got == [h[key] for h in res.history], (name, got)
+        assert all(0 < i <= CFG["max_cg_iters"] for i in got), got
 
 
 def test_engine_query_spans_share_a_query_id():

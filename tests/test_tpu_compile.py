@@ -17,6 +17,7 @@ this file's tests from the fixture instead of breaking collection.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -129,12 +130,17 @@ def test_frozen_slice_compiles(spec):
              spec((BUCKET, D), jnp.float32))
 
 
-def test_mll_value_and_grad_step_compiles(spec):
+def test_mll_value_and_grad_step_compiles(spec, cg_loop):
     params = jax.tree.map(lambda a: spec(a.shape, a.dtype), GPParams.init(D))
     compiled = _compile(
         lambda p, x, y, k: mll_value_and_grad(MODEL, p, x, y, k, cap=CAP),
         params, spec((N, D), jnp.float32), spec((N,), jnp.float32),
         spec((2,), jnp.uint32))
+    # CG is a while loop that exits on its residuals: its condition reads
+    # the (1 + num_probes,) active mask, not only the trip counter
+    _, cond = cg_loop(compiled.as_text())
+    assert any(re.search(rf"= pred\[{C_TRAIN}\]\S* get-tuple-element\(", ln)
+               for ln in cond), cond
     # the step must fit one v5e chip's 16 GB of HBM
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
